@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.teda import TedaOutput, TedaState, teda_threshold
-from repro.sharding.rules import shard_map_compat
 
 __all__ = ["distributed_teda", "make_distributed_teda"]
 
@@ -119,12 +118,12 @@ def make_distributed_teda(mesh: Mesh, axis_name: str = "data"):
     state (every device ends with the full-stream statistics).
     """
     body = functools.partial(_local_shard_scan, axis_name=axis_name)
-    mapped = shard_map_compat(
+    mapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis_name, None), P()),
         out_specs=(TedaState(k=P(), mean=P(), var=P()),
                    TedaOutput(*([P(axis_name)] * 6))),
-        check=False,
+        check_vma=False,
     )
     x_sh = NamedSharding(mesh, P(axis_name, None))
     m_sh = NamedSharding(mesh, P())

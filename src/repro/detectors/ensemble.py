@@ -176,7 +176,8 @@ def ensemble_scan(x: jnp.ndarray, m=3.0,
     bits, vote, fk, auxf, scores = _padded_ensemble_call(
         x, vlen, k0, mv, thr, sel, jnp.asarray(state.aux, jnp.float32),
         window=window, detectors=detectors, fmt=fmt, block_t=block_t,
-        block_c=norm_block_c(block_c), interpret=interpret,
+        block_c=norm_block_c(block_c, block_t, c, lane_pad),
+        interpret=interpret,
         lane_pad=lane_pad)
     final = EnsembleState(k=fk, aux=auxf)
     return final, {"det_flags": bits, "vote": vote.astype(bool),

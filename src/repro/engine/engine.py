@@ -17,7 +17,9 @@ releasing them (the continuous-batching suspend, `launch/batching.py`).
 
 With a `mesh`, chunk processing fans out over the channel axis via
 `shard_map` (`sharding.rules.make_channel_fanout`) — channels are
-independent, so multi-device scale needs no collectives.
+independent, so multi-device scale needs no collectives.  With a
+`device`, the packed state is committed to that one device, so every
+call on it runs there (one shard per chip in `engine/sharded.py`).
 """
 from __future__ import annotations
 
@@ -54,10 +56,14 @@ class StreamEngine:
                  m: float = 3.0, fmt=None, block_t: int = 256,
                  block_c: Optional[int] = None,
                  interpret: Optional[bool] = None, lane_pad: int = 128,
-                 mesh=None, axis_name: str = "data",
+                 mesh=None, axis_name: str = "data", device=None,
                  auto_attach: bool = True, registry=None, tracer=None,
                  name: Optional[str] = None, **backend_opts):
+        if mesh is not None and device is not None:
+            raise ValueError("pass a mesh (channel fan-out) or a device "
+                             "(single-device placement), not both")
         self.capacity = int(capacity)
+        self.device = device
         self.default_m = float(m)
         # observability (repro.obs): process-call / samples-retired /
         # program-compile counters, labelled by engine instance; the
@@ -145,6 +151,18 @@ class StreamEngine:
                     f"axis {axis_name!r} ({n_shards} shards)")
             core = make_channel_fanout(core, mesh, axis_name)
         self._fn = jax.jit(core)
+
+    @property
+    def state(self) -> EngineState:
+        return self._state
+
+    @state.setter
+    def state(self, st: EngineState) -> None:
+        # a pinned engine re-commits every state it is handed (resizes
+        # and migrations build theirs from host arrays), so its jitted
+        # calls keep following the state onto its device
+        self._state = (st if self.device is None
+                       else jax.device_put(st, self.device))
 
     # ------------------------------------------------------ slot admin
     def attach(self, slots=None, n: Optional[int] = None, *,

@@ -27,12 +27,16 @@ the three things a fleet needs that a single pool does not have:
     flight (`avoid=`); each move is counted, gauged and published as a
     `shard_migrated` event on the wired `EventBus`.
 
-With `devices=`, each shard gets its own single-axis `jax.sharding.Mesh`
-over its device group and the per-shard engines fan processing out over
-the channel axis via `sharding.rules.make_channel_fanout` — the bucket
-ladder must stay divisible by the per-shard device count so every
-bucket capacity shards evenly.  Without `devices=`, shards share the
-default device (the CPU-only CI case: `XLA_FLAGS=
+With `devices=`, the devices split evenly into one group per shard.  A
+one-device group pins the shard's engines to that device (their state
+is committed there, so every call of the shard runs there — any
+backend, the detector ensemble included).  A larger group gets its own
+single-axis `jax.sharding.Mesh` and the per-shard engines fan
+processing out over the channel axis via
+`sharding.rules.make_channel_fanout` — the bucket ladder must stay
+divisible by the per-shard device count so every bucket capacity
+shards evenly.  Without `devices=`, shards share the default device
+(the CPU-only CI case: `XLA_FLAGS=
 --xla_force_host_platform_device_count=8` makes 8 virtual devices).
 
 Behavior contract (tests/test_sharded.py): a K-shard pool is bit-exact
@@ -159,15 +163,12 @@ class ShardedPool:
         self.events = events  # optional EventBus for shard_migrated
         self.name = auto_name("shpool") if name is None else str(name)
         self.ring = HashRing(range(self.n_shards), vnodes=vnodes)
-        meshes = self._shard_meshes(devices, buckets, axis_name)
-        self.pools: List[SlotPool] = []
-        for s in range(self.n_shards):
-            opts = dict(engine_opts)
-            if meshes[s] is not None:
-                opts.update(mesh=meshes[s], axis_name=axis_name)
-            self.pools.append(SlotPool(
-                backend, buckets=buckets, m=m, registry=self.registry,
-                tracer=self.tracer, name=f"{self.name}/s{s}", **opts))
+        placements = self._shard_placements(devices, buckets, axis_name)
+        self.pools: List[SlotPool] = [
+            SlotPool(backend, buckets=buckets, m=m, registry=self.registry,
+                     tracer=self.tracer, name=f"{self.name}/s{s}",
+                     **engine_opts, **placements[s])
+            for s in range(self.n_shards)]
         self._placement: Dict[str, Tuple[int, int]] = {}
         lbl = {"pool": self.name}
         self._c_migrations = self.registry.counter(
@@ -183,17 +184,20 @@ class ShardedPool:
             self._f_shard_occ.labels(pool=self.name, shard=str(s))
             for s in range(self.n_shards)]
 
-    def _shard_meshes(self, devices, buckets, axis_name):
-        """Per-shard 1-axis meshes over equal device groups (None per
-        shard when no devices are pinned)."""
+    def _shard_placements(self, devices, buckets, axis_name):
+        """Per-shard engine placement options over equal device groups:
+        {} when no devices are pinned, {"device": d} for one device per
+        shard, a 1-axis {"mesh": ...} fan-out for larger groups."""
         if devices is None:
-            return [None] * self.n_shards
+            return [{}] * self.n_shards
         devices = list(devices)
         if not devices or len(devices) % self.n_shards:
             raise ValueError(
                 f"{len(devices)} devices do not split evenly over "
                 f"{self.n_shards} shards")
         per = len(devices) // self.n_shards
+        if per == 1:
+            return [{"device": d} for d in devices]
         bad = [b for b in buckets if b % per]
         if bad:
             raise ValueError(
@@ -201,8 +205,9 @@ class ShardedPool:
                 f"shard mesh (the channel fan-out needs capacity % "
                 f"devices == 0)")
         from jax.sharding import Mesh
-        return [Mesh(np.asarray(devices[s * per:(s + 1) * per]),
-                     (axis_name,))
+        return [{"mesh": Mesh(np.asarray(devices[s * per:(s + 1) * per]),
+                              (axis_name,)),
+                 "axis_name": axis_name}
                 for s in range(self.n_shards)]
 
     # ------------------------------------------------------- topology

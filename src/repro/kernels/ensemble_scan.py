@@ -59,23 +59,27 @@ from repro.detectors.spec import (HST_LEAVES, HST_RANGE, MOMENT_MEMBERS,
 from repro.fixedpoint.qformat import sat_add, sat_mul, sat_sub
 from repro.kernels.qdiv import fast_div_qi, fast_div_qq
 from repro.kernels.teda_scan import (_affine_scan_rows, _cumsum_rows,
-                                     block_spec, tpu_compiler_params)
+                                     row_index)
 
 __all__ = ["ensemble_scan_kernel", "ensemble_pallas_call"]
 
+# (block_t, block_c) VMEM row banks each sequential lane reads and
+# writes one row at a time (Mosaic lowers a dynamic row slice of a
+# ref, not of a value): hst banks its scores and flags (f32), teda-q
+# its rk / divider-term / mean / var rows (int32)
+_HST_BANKS = 2
+_TEDA_Q_BANKS = 4
 
-def _row(a, r):
-    return jax.lax.dynamic_slice_in_dim(a, r, 1, 0)
 
-
-def _hst_lane(state, spec, x, valid, m, *, window: int):
+def _hst_lane(state, spec, x_ref, row_valid, m, banks, *, window: int):
     """Advance the "hst" opaque regions; returns (flags, scores).
 
     Sequential per-row loop (the window flip is a data-dependent state
     machine, not a scan), but every op is an exact small-integer f32
     add/compare — identical bits to the `hst_scan` oracle step.
     """
-    bt, bc = x.shape
+    bt = x_ref.shape[0]
+    score_bank, flag_bank = banks
     ell = HST_LEAVES
     off = spec.offset("hst:ref")
     ref0 = state[off:off + ell, :]
@@ -83,15 +87,15 @@ def _hst_lane(state, spec, x, valid, m, *, window: int):
     ph0 = state[off + 2 * ell:off + 2 * ell + 1, :]
     lo, hi = HST_RANGE
     scale = float(ell) / (hi - lo)
-    lf = jnp.clip(jnp.floor((x - lo) * scale), 0.0, float(ell - 1))
-    leaves = jax.lax.broadcasted_iota(jnp.float32, (ell, 1), 0)
+    leaves = row_index(ell)
     wn = float(int(window) * ell)
-    zero = jnp.zeros((bt, bc), jnp.float32)
 
     def body(r, carry):
-        ref, cur, ph, scores, flags = carry
-        lf_r = _row(lf, r)                         # (1, bc)
-        v_r = _row(valid, r)                       # (1, bc) bool
+        ref, cur, ph = carry
+        x_r = x_ref[pl.ds(r, 1), :].astype(jnp.float32)  # (1, bc)
+        lf_r = jnp.clip(jnp.floor((x_r - lo) * scale), 0.0,
+                        float(ell - 1))
+        v_r = row_valid(r)                         # (1, bc) bool
         onehot = leaves == lf_r                    # (ell, bc)
         score = jnp.sum(jnp.where(onehot, ref, 0.0), axis=0,
                         keepdims=True)
@@ -103,21 +107,18 @@ def _hst_lane(state, spec, x, valid, m, *, window: int):
         ref1 = jnp.where(flip, cur1, ref)
         cur2 = jnp.where(flip, 0.0, cur1)
         ph2 = jnp.where(flip, 0.0, ph1)
-        scores = jax.lax.dynamic_update_slice(
-            scores, jnp.where(v_r, score, 0.0), (r, 0))
-        flags = jax.lax.dynamic_update_slice(
-            flags, flag.astype(jnp.float32), (r, 0))
-        return ref1, cur2, ph2, scores, flags
+        score_bank[pl.ds(r, 1), :] = jnp.where(v_r, score, 0.0)
+        flag_bank[pl.ds(r, 1), :] = flag.astype(jnp.float32)
+        return ref1, cur2, ph2
 
-    ref_f, cur_f, ph_f, scores, flags = jax.lax.fori_loop(
-        0, bt, body, (ref0, cur0, ph0, zero, zero))
+    ref_f, cur_f, ph_f = jax.lax.fori_loop(0, bt, body, (ref0, cur0, ph0))
     state[off:off + ell, :] = ref_f
     state[off + ell:off + 2 * ell, :] = cur_f
     state[off + 2 * ell:off + 2 * ell + 1, :] = ph_f
-    return flags > 0.0, scores
+    return flag_bank[...] > 0.0, score_bank[...]
 
 
-def _teda_q_lane(state, spec, x, valid, k, m, fmt):
+def _teda_q_lane(state, spec, x, valid, row_valid, k, m, fmt, banks):
     """Advance the "teda-q" opaque Q registers; returns (flags, scores).
 
     The `teda_q_scan.py` kernel's rescheduled datapath on the member's
@@ -130,7 +131,8 @@ def _teda_q_lane(state, spec, x, valid, k, m, fmt):
     each element sees the same inputs and operation order, with the
     k=1 overrides folded into the hoisted terms (rk = 0 and x/1 = x).
     """
-    bt, bc = x.shape
+    bt = x.shape[0]
+    rk_bank, term_bank, mean_bank, var_bank = banks
     i32 = jnp.int32
     offm = spec.offset("teda-q:mean")
     offv = spec.offset("teda-q:var")
@@ -141,33 +143,31 @@ def _teda_q_lane(state, spec, x, valid, k, m, fmt):
     kv = k.astype(i32)                      # exact: k < 2^24
     first = kv <= 1
 
-    rk_b = fast_div_qq(fmt, kv - 1, kv)
+    rk_bank[...] = fast_div_qq(fmt, kv - 1, kv)
     inv_b = fast_div_qi(fmt, jnp.broadcast_to(i32(fmt.one), kv.shape), kv)
     thr_b = fast_div_qi(fmt, jnp.broadcast_to(msq1, kv.shape), 2 * kv)
-    xk_b = fast_div_qi(fmt, xq, kv)
-    zero = jnp.zeros((bt, bc), i32)
+    term_bank[...] = fast_div_qi(fmt, xq, kv)   # x/k, the MEAN term
 
-    def mean_row(r, carry):
-        mean, bank = carry
-        mean_n = sat_add(fmt, sat_mul(fmt, _row(rk_b, r), mean),
-                         _row(xk_b, r))
-        bank = jax.lax.dynamic_update_slice(bank, mean_n, (r, 0))
-        return jnp.where(_row(valid, r), mean_n, mean), bank
+    def mean_row(r, mean):
+        mean_n = sat_add(fmt, sat_mul(fmt, rk_bank[pl.ds(r, 1), :], mean),
+                         term_bank[pl.ds(r, 1), :])
+        mean_bank[pl.ds(r, 1), :] = mean_n
+        return jnp.where(row_valid(r), mean_n, mean)
 
-    mean_f, mean_b = jax.lax.fori_loop(0, bt, mean_row, (mean0, zero))
+    mean_f = jax.lax.fori_loop(0, bt, mean_row, mean0)
 
-    d_b = sat_sub(fmt, xq, mean_b)
+    d_b = sat_sub(fmt, xq, mean_bank[...])
     d2_b = sat_mul(fmt, d_b, d_b)
-    e_b = jnp.where(first, 0, fast_div_qi(fmt, d2_b, kv))
+    term_bank[...] = jnp.where(first, 0, fast_div_qi(fmt, d2_b, kv))
 
-    def var_row(r, carry):
-        var, bank = carry
-        var_n = sat_add(fmt, sat_mul(fmt, _row(rk_b, r), var),
-                        _row(e_b, r))
-        bank = jax.lax.dynamic_update_slice(bank, var_n, (r, 0))
-        return jnp.where(_row(valid, r), var_n, var), bank
+    def var_row(r, var):
+        var_n = sat_add(fmt, sat_mul(fmt, rk_bank[pl.ds(r, 1), :], var),
+                        term_bank[pl.ds(r, 1), :])
+        var_bank[pl.ds(r, 1), :] = var_n
+        return jnp.where(row_valid(r), var_n, var)
 
-    var_f, var_b = jax.lax.fori_loop(0, bt, var_row, (var0, zero))
+    var_f = jax.lax.fori_loop(0, bt, var_row, var0)
+    var_b = var_bank[...]
 
     safe = var_b > 0
     ratio = fast_div_qq(fmt, d2_b, jnp.where(safe, var_b, 1))
@@ -184,8 +184,10 @@ def ensemble_scan_kernel(x_ref, vlen_ref, k0_ref, m_ref, thr_ref, sel_ref,
                          aux_ref, bits_ref, vote_ref, fk_ref, aux_out_ref,
                          *rest, block_t: int, window: int,
                          detectors: tuple, fmt=None):
-    score_refs = rest[:-1]          # K per-detector (bt, bc) f32 outputs
-    state = rest[-1]                # the (spec.rows, bc) scratch tile
+    n_det = len(detectors)
+    score_refs = rest[:n_det]       # K per-detector (bt, bc) f32 outputs
+    state = rest[n_det]             # the (spec.rows, bc) scratch tile
+    banks = list(rest[n_det + 1:])  # row banks of the sequential lanes
     spec = ensemble_spec(detectors, window)
     w = window
     moment = any(d in MOMENT_MEMBERS for d in detectors)
@@ -205,8 +207,7 @@ def ensemble_scan_kernel(x_ref, vlen_ref, k0_ref, m_ref, thr_ref, sel_ref,
     vlen = vlen_ref[...].astype(jnp.float32)  # (1, bc)
     m = m_ref[...].astype(jnp.float32)        # (1, bc) per-channel m
     thr = thr_ref[...].astype(jnp.float32)    # (1, bc) vote threshold
-    t = jax.lax.broadcasted_iota(jnp.float32, (bt, 1), 0)
-    g = i * block_t + t                # global row index, (bt, 1)
+    g = i * block_t + row_index(bt)    # global row index, (bt, 1)
     valid = g < vlen                   # ragged-tail mask, (bt, bc)
     k = k0 + g + 1.0                   # per-channel iteration index
     m2 = m * m
@@ -272,7 +273,7 @@ def ensemble_scan_kernel(x_ref, vlen_ref, k0_ref, m_ref, thr_ref, sel_ref,
         # of 2-D masked reductions — one per tail row — instead of a
         # 3-D gather (sublane-dynamic indexing is not a Mosaic op).
         n_valid = jnp.clip(vlen - i * block_t, 0.0, float(bt))  # (1, c)
-        rows = jax.lax.broadcasted_iota(jnp.float32, (bt + w, 1), 0)
+        rows = row_index(bt + w)
         new_s, new_s2 = [], []
         for j in range(w):
             hit = rows == (n_valid + float(j))  # (bt+w, c), exact f32
@@ -288,12 +289,16 @@ def ensemble_scan_kernel(x_ref, vlen_ref, k0_ref, m_ref, thr_ref, sel_ref,
             state[2 * w - 1:2 * w, :] = s2[block_t - 1:block_t]
 
     # ---- opaque-region members: per-member state-advance dispatch -----
+    def row_valid(r):  # (1, bc) ragged mask of in-block row r
+        return (i * block_t + r).astype(jnp.float32) < vlen
+
     if "hst" in detectors:
-        flags["hst"], scores["hst"] = _hst_lane(state, spec, x, valid, m,
-                                                window=window)
+        hst_banks, banks = banks[:_HST_BANKS], banks[_HST_BANKS:]
+        flags["hst"], scores["hst"] = _hst_lane(
+            state, spec, x_ref, row_valid, m, hst_banks, window=window)
     if "teda-q" in detectors:
         flags["teda-q"], scores["teda-q"] = _teda_q_lane(
-            state, spec, x, valid, k, m, fmt)
+            state, spec, x, valid, row_valid, k, m, fmt, banks)
 
     # ---- selection-masked bitmask + weighted vote + score streams -----
     bits = jnp.zeros((bt, c), jnp.int32)
@@ -350,15 +355,19 @@ def ensemble_pallas_call(x: jnp.ndarray, vlen: jnp.ndarray,
         raise ValueError("the teda-q member needs fmt=QFormat(...)")
     grid = (c // block_c, t_len // block_t)
 
-    row_spec = block_spec((block_t, block_c), lambda j, i: (i, j),
-                          memory_space=pltpu.VMEM)
-    carry_spec = block_spec((1, block_c), lambda j, i: (0, j),
+    row_spec = pl.BlockSpec((block_t, block_c), lambda j, i: (i, j),
                             memory_space=pltpu.VMEM)
-    sel_spec = block_spec((len(detectors), block_c), lambda j, i: (0, j),
-                          memory_space=pltpu.VMEM)
-    aux_spec = block_spec((n_aux, block_c), lambda j, i: (0, j),
-                          memory_space=pltpu.VMEM)
+    carry_spec = pl.BlockSpec((1, block_c), lambda j, i: (0, j),
+                              memory_space=pltpu.VMEM)
+    sel_spec = pl.BlockSpec((len(detectors), block_c), lambda j, i: (0, j),
+                            memory_space=pltpu.VMEM)
+    aux_spec = pl.BlockSpec((n_aux, block_c), lambda j, i: (0, j),
+                            memory_space=pltpu.VMEM)
     f32 = jnp.float32
+    banks = ([pltpu.VMEM((block_t, block_c), f32)] * _HST_BANKS
+             if "hst" in detectors else [])
+    if "teda-q" in detectors:
+        banks += [pltpu.VMEM((block_t, block_c), jnp.int32)] * _TEDA_Q_BANKS
     out_shape = [
         jax.ShapeDtypeStruct((t_len, c), jnp.int32),  # detector bitmask
         jax.ShapeDtypeStruct((t_len, c), jnp.int8),   # fused vote
@@ -378,7 +387,7 @@ def ensemble_pallas_call(x: jnp.ndarray, vlen: jnp.ndarray,
                                fmt=fmt)
     compiler_params = None
     if not interpret:
-        compiler_params = tpu_compiler_params(
+        compiler_params = pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"))
     return pl.pallas_call(
         kernel,
@@ -389,7 +398,7 @@ def ensemble_pallas_call(x: jnp.ndarray, vlen: jnp.ndarray,
         out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((n_aux, block_c), f32),  # the packed StateSpec
-        ],
+        ] + banks,
         input_output_aliases=aliases,
         compiler_params=compiler_params,
         interpret=interpret,

@@ -31,7 +31,8 @@ which is guaranteed False there.
 `block_c` tiles the channel axis into independent grid strips (the
 kernels' 2-D `(channel-block, time-block)` grid); channels are fully
 independent in TEDA, so every block_c produces identical bits — `None`
-keeps one strip spanning all lanes (the 1-D-grid behavior).  On
+keeps one strip spanning all lanes while the tile fits the VMEM budget
+(`kernels/ragged.py` `TILE_ELEMS`) and splits wider pools.  On
 multi-core TPUs the strips are the unit of core parallelism; the
 channel extent is padded up to a block multiple and padded lanes carry
 vlen=0 (frozen at state zero, no verdicts).
@@ -59,7 +60,6 @@ __all__ = ["teda_scan_tpu", "teda_scan_verdict", "teda_q_scan_tpu",
 # the helpers moved to `kernels/ragged.py` (shared with the ensemble
 # wrapper); the underscore aliases remain for existing importers
 _round_up = round_up
-_norm_block_c = norm_block_c
 _vlen_vec = vlen_vec
 _mask_ragged_rows = mask_ragged_rows
 _pad_layout = pad_layout
@@ -157,7 +157,7 @@ def teda_scan_verdict(x: jnp.ndarray, m: float | jnp.ndarray = 3.0,
     per_slot = m_arr.ndim > 0
     ecc, outlier, fk, fsum, fvar = _padded_call(
         x, jnp.float32(0.0) if per_slot else m_arr, vlen, k0, mean0 * k0,
-        var0, block_t=block_t, block_c=_norm_block_c(block_c),
+        var0, block_t=block_t, block_c=norm_block_c(block_c, block_t, c, lane_pad),
         interpret=interpret, lane_pad=lane_pad, verdict_only=True)
     if per_slot:
         k_all = _k_rows(k0, t_len, jnp.float32)
@@ -198,7 +198,7 @@ def teda_scan_tpu(x: jnp.ndarray, m: float | jnp.ndarray = 3.0,
 
     mean, var, ecc, outlier, fk, fsum, fvar = _padded_call(
         x, jnp.float32(0.0) if per_slot else m_arr, vlen, k0, mean0 * k0,
-        var0, block_t=block_t, block_c=_norm_block_c(block_c),
+        var0, block_t=block_t, block_c=norm_block_c(block_c, block_t, c, lane_pad),
         interpret=interpret, lane_pad=lane_pad, verdict_only=False)
 
     k_all = _k_rows(k0, t_len, jnp.float32)
@@ -256,7 +256,7 @@ def teda_q_scan_verdict(x: jnp.ndarray, fmt: QFormat,
 
     ecc, outlier, fk, fmean, fvar = _padded_q_call(
         xq, jnp.int32(0) if per_slot else msq1, vlen, k0, mean0, var0,
-        fmt=fmt, block_t=block_t, block_c=_norm_block_c(block_c),
+        fmt=fmt, block_t=block_t, block_c=norm_block_c(block_c, block_t, c, lane_pad),
         interpret=interpret, lane_pad=lane_pad, verdict_only=True)
 
     if per_slot:
@@ -309,7 +309,7 @@ def teda_q_scan_tpu(x: jnp.ndarray, fmt: QFormat,
 
     mean, var, ecc, outlier, fk, fmean, fvar = _padded_q_call(
         xq, jnp.int32(0) if per_slot else msq1, vlen, k0, mean0, var0,
-        fmt=fmt, block_t=block_t, block_c=_norm_block_c(block_c),
+        fmt=fmt, block_t=block_t, block_c=norm_block_c(block_c, block_t, c, lane_pad),
         interpret=interpret, lane_pad=lane_pad, verdict_only=False)
 
     k_all = _k_rows(k0, t_len, jnp.int32)

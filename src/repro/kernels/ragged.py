@@ -14,21 +14,53 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["default_interpret", "round_up", "norm_block_c", "vlen_vec",
-           "mask_ragged_rows", "pad_layout"]
+           "mask_ragged_rows", "pad_layout", "TILE_ELEMS"]
+
+# Default cap on a kernel tile, block_t * block_c elements.  The widest
+# tiles that compile within a TPU v5e's scoped VMEM at block_t=256 are
+# 256 x 256 for the K=5 ensemble (the tightest kernel: score streams
+# plus the hst / teda-q row banks), 256 x 512 for the Q verdict kernel
+# and the K=3 ensemble, and 256 x 1024 for the float verdict kernel
+# (tests/test_tpu_compile.py compiles the main path at this default).
+TILE_ELEMS = 256 * 256
 
 
 def default_interpret() -> bool:
-    """Interpret (CPU emulation) unless a real TPU backend is attached."""
-    return jax.default_backend() != "tpu"
+    """Whether the Pallas kernels run in interpret mode by default.
+
+    Interpret mode is the CPU test path only: on the "cpu" backend the
+    kernels are emulated, on "tpu" they compile with Mosaic.  Any other
+    platform raises instead of silently emulating the kernels there.
+    """
+    platform = jax.default_backend()
+    if platform not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"the Pallas kernels target TPU (interpret mode on CPU for "
+            f"tests); no path for the {platform!r} backend")
+    return platform == "cpu"
 
 
 def round_up(v: int, mult: int) -> int:
     return -(-v // mult) * mult
 
 
-def norm_block_c(block_c) -> int:
-    """Normalize the channel-block width to a static int (0 = one strip)."""
-    bc = int(block_c or 0)
+def norm_block_c(block_c, block_t: int, c: int, lane_pad: int) -> int:
+    """Normalize the channel-block width to a static int (0 = one strip).
+
+    `None` picks the default: one strip when the lane-padded width fits
+    the `TILE_ELEMS` tile budget at this `block_t`, else the widest
+    strip (a multiple of 128 dividing the padded width) that does.
+    """
+    if block_c is None:
+        cp = round_up(c, lane_pad)
+        cap = TILE_ELEMS // block_t
+        if cp <= cap:
+            return 0
+        bc = max(128, cap // 128 * 128)
+        while bc > 128 and cp % bc:
+            bc -= 128
+        return bc
+    bc = int(block_c)
     if bc and bc % 128 != 0:
         raise ValueError(f"block_c must be a multiple of 128, got {bc}")
     return bc

@@ -70,7 +70,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.fixedpoint.qformat import QFormat, sat_add, sat_mul, sat_sub
 from repro.kernels.qdiv import fast_div_qi, fast_div_qq
-from repro.kernels.teda_scan import block_spec, tpu_compiler_params
 
 __all__ = ["teda_q_scan_kernel", "teda_q_pallas_call"]
 
@@ -81,12 +80,13 @@ def teda_q_scan_kernel(scal_ref, x_ref, vlen_ref, init_k_ref,
                        verdict_only: bool = False):
     if verdict_only:
         ecc_ref, outlier_ref, fk_ref, fmean_ref, fvar_ref = out_refs[:5]
-        mean_carry, var_carry, mean_scr, var_scr = out_refs[5:]
+        scratch = out_refs[5:]
         mean_ref = var_ref = None
     else:
         (mean_ref, var_ref, ecc_ref, outlier_ref, fk_ref, fmean_ref,
          fvar_ref) = out_refs[:7]
-        mean_carry, var_carry, mean_scr, var_scr = out_refs[7:]
+        scratch = out_refs[7:]
+    mean_carry, var_carry, mean_scr, var_scr, rk_scr, term_scr = scratch
     i = pl.program_id(1)  # time block (sequential, carry-chained)
 
     # a new channel strip restarts the time sweep: re-seed its carries
@@ -100,12 +100,10 @@ def teda_q_scan_kernel(scal_ref, x_ref, vlen_ref, init_k_ref,
     k0 = init_k_ref[...]  # (1, bc) int32 per-channel counter offset
     xb = x_ref[...]       # (block_t, bc) int32 Q samples
 
-    # the FPGA's counter register for every row of the block, plus the
-    # whole-block iteration index and ragged mask
+    # the FPGA's counter register for every row of the block
     row_iota = jax.lax.broadcasted_iota(jnp.int32, (block_t, 1), 0)
     kv = k0 + i * block_t + 1 + row_iota     # (block_t, bc)
     first_b = kv <= 1
-    valid_b = (i * block_t + row_iota) < vlen
 
     # every data-independent divider, vectorized over the whole block:
     # the counter-only triple (rk = (k-1)/k, 1/k, thr = (m^2+1)/2k) of
@@ -119,10 +117,13 @@ def teda_q_scan_kernel(scal_ref, x_ref, vlen_ref, init_k_ref,
     thr_b = fast_div_qi(fmt, jnp.broadcast_to(jnp.asarray(msq1,
                                                           jnp.int32),
                                               kv.shape), 2 * kv)
-    xk_b = fast_div_qi(fmt, xb, kv)
+    # the row loops read their per-row terms back through VMEM refs:
+    # Mosaic lowers a dynamic row slice of a ref, not of a value
+    rk_scr[...] = rk_b
+    term_scr[...] = fast_div_qi(fmt, xb, kv)   # x/k, the MEAN term
 
-    def _row(a, r):
-        return jax.lax.dynamic_slice_in_dim(a, r, 1, 0)
+    def row_valid(r):
+        return i * block_t + r < vlen  # (1, bc) ragged mask of row r
 
     # MEAN recurrence, eq (2): mu = rk * mu + x/k — a bare saturating
     # multiply-add per row, the MEAN module's accumulator register.  The
@@ -131,11 +132,11 @@ def teda_q_scan_kernel(scal_ref, x_ref, vlen_ref, init_k_ref,
     # by one is exact in the restoring divider, and x is in-format), so
     # the multiply-add itself yields x.
     def mean_row(r, mean):
-        mean_n = sat_add(fmt, sat_mul(fmt, _row(rk_b, r), mean),
-                         _row(xk_b, r))
+        mean_n = sat_add(fmt, sat_mul(fmt, rk_scr[pl.ds(r, 1), :], mean),
+                         term_scr[pl.ds(r, 1), :])
         mean_scr[pl.ds(r, 1), :] = mean_n
         # each channel's ragged tail must not advance its carried state
-        return jnp.where(_row(valid_b, r), mean_n, mean)
+        return jnp.where(row_valid(r), mean_n, mean)
 
     mean_carry[...] = jax.lax.fori_loop(
         0, block_t, mean_row, mean_carry[...])
@@ -148,17 +149,17 @@ def teda_q_scan_kernel(scal_ref, x_ref, vlen_ref, init_k_ref,
     mean_b = mean_scr[...]
     d_b = sat_sub(fmt, xb, mean_b)
     d2_b = sat_mul(fmt, d_b, d_b)
-    e_b = jnp.where(first_b, 0, fast_div_qi(fmt, d2_b, kv))
+    term_scr[...] = jnp.where(first_b, 0, fast_div_qi(fmt, d2_b, kv))
     if not verdict_only:
         mean_ref[...] = mean_b
 
     # VARIANCE recurrence: var = rk * var + d2/k — the second
     # accumulator register, again a bare multiply-add per row
     def var_row(r, var):
-        var_n = sat_add(fmt, sat_mul(fmt, _row(rk_b, r), var),
-                        _row(e_b, r))
+        var_n = sat_add(fmt, sat_mul(fmt, rk_scr[pl.ds(r, 1), :], var),
+                        term_scr[pl.ds(r, 1), :])
         var_scr[pl.ds(r, 1), :] = var_n
-        return jnp.where(_row(valid_b, r), var_n, var)
+        return jnp.where(row_valid(r), var_n, var)
 
     var_carry[...] = jax.lax.fori_loop(0, block_t, var_row, var_carry[...])
 
@@ -216,10 +217,10 @@ def teda_q_pallas_call(x: jnp.ndarray, scal: jnp.ndarray,
         "C % block_c == 0, block_c % 128 == 0")
     grid = (c // block_c, t_len // block_t)
 
-    row_spec = block_spec((block_t, block_c), lambda j, i: (i, j),
-                          memory_space=pltpu.VMEM)
-    carry_spec = block_spec((1, block_c), lambda j, i: (0, j),
+    row_spec = pl.BlockSpec((block_t, block_c), lambda j, i: (i, j),
                             memory_space=pltpu.VMEM)
+    carry_spec = pl.BlockSpec((1, block_c), lambda j, i: (0, j),
+                              memory_space=pltpu.VMEM)
     i32 = jnp.int32
     final_shape = [
         jax.ShapeDtypeStruct((1, c), i32),  # final k
@@ -253,7 +254,7 @@ def teda_q_pallas_call(x: jnp.ndarray, scal: jnp.ndarray,
                                fmt=fmt, verdict_only=verdict_only)
     compiler_params = None
     if not interpret:
-        compiler_params = tpu_compiler_params(
+        compiler_params = pltpu.CompilerParams(
             # channel strips are independent (multi-core scaling); the
             # time axis is the sequential carry chain
             dimension_semantics=("parallel", "arbitrary"))
@@ -276,6 +277,8 @@ def teda_q_pallas_call(x: jnp.ndarray, scal: jnp.ndarray,
             pltpu.VMEM((1, block_c), i32),        # running var carry
             pltpu.VMEM((block_t, block_c), i32),  # banked mean rows
             pltpu.VMEM((block_t, block_c), i32),  # banked var rows
+            pltpu.VMEM((block_t, block_c), i32),  # rk = (k-1)/k rows
+            pltpu.VMEM((block_t, block_c), i32),  # x/k, then d2/k rows
         ],
         compiler_params=compiler_params,
         interpret=interpret,

@@ -49,36 +49,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["teda_scan_kernel", "teda_pallas_call", "tpu_compiler_params"]
+__all__ = ["teda_scan_kernel", "teda_pallas_call", "row_index"]
 
 
-def tpu_compiler_params(**kw):
-    """Version-compatible Pallas TPU CompilerParams.
+def row_index(n: int) -> jnp.ndarray:
+    """(n, 1) float32 sublane index 0..n-1.
 
-    The class is TPUCompilerParams on jax 0.4.x and CompilerParams on
-    newer releases; without this shim the compiled (non-interpret) TPU
-    path raises AttributeError on one side of the rename.
+    Mosaic's iota yields integers only, so the index is built as int32
+    and cast; the values are small integers, exact in float32.
     """
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kw)
-
-
-def block_spec(shape, index_map, memory_space=None):
-    """Version-compatible BlockSpec with explicit memory-space placement.
-
-    Blocked operands live in VMEM (the compute-adjacent space the tile
-    sizes are budgeted against); older jax releases reject the
-    `memory_space` kwarg next to a block shape, so placement degrades
-    to the default on that side of the API.
-    """
-    if memory_space is None:
-        return pl.BlockSpec(shape, index_map)
-    try:
-        return pl.BlockSpec(shape, index_map, memory_space=memory_space)
-    except TypeError:  # old jax: block shape + memory space unsupported
-        return pl.BlockSpec(shape, index_map)
+    return jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0).astype(
+        jnp.float32)
 
 
 def _shift_down(v: jnp.ndarray, d: int, fill: float) -> jnp.ndarray:
@@ -142,8 +123,7 @@ def teda_scan_kernel(scal_ref, x_ref, vlen_ref, init_k_ref, init_sum_ref,
     bt, c = x.shape
     k0 = init_k_ref[...].astype(jnp.float32)  # (1, bc) per-channel offset
     vlen = vlen_ref[...].astype(jnp.float32)  # (1, bc) per-channel length
-    t = jax.lax.broadcasted_iota(jnp.float32, (bt, 1), 0)
-    g = i * block_t + t               # global row index, (bt, 1)
+    g = i * block_t + row_index(bt)   # global row index, (bt, 1)
     valid = g < vlen                  # ragged-tail mask, (bt, bc)
     k = k0 + g + 1.0                  # per-channel iteration index, (bt, bc)
 
@@ -222,10 +202,10 @@ def teda_pallas_call(x: jnp.ndarray, scal: jnp.ndarray, vlen: jnp.ndarray,
         "C % block_c == 0, block_c % 128 == 0")
     grid = (c // block_c, t_len // block_t)
 
-    row_spec = block_spec((block_t, block_c), lambda j, i: (i, j),
-                          memory_space=pltpu.VMEM)
-    carry_spec = block_spec((1, block_c), lambda j, i: (0, j),
+    row_spec = pl.BlockSpec((block_t, block_c), lambda j, i: (i, j),
                             memory_space=pltpu.VMEM)
+    carry_spec = pl.BlockSpec((1, block_c), lambda j, i: (0, j),
+                              memory_space=pltpu.VMEM)
     f32 = jnp.float32
     final_shape = [
         jax.ShapeDtypeStruct((1, c), f32),  # final k (= k0 + vlen)
@@ -260,7 +240,7 @@ def teda_pallas_call(x: jnp.ndarray, scal: jnp.ndarray, vlen: jnp.ndarray,
                                verdict_only=verdict_only)
     compiler_params = None
     if not interpret:
-        compiler_params = tpu_compiler_params(
+        compiler_params = pltpu.CompilerParams(
             # channel strips are independent (multi-core scaling); the
             # time axis is the sequential carry chain
             dimension_semantics=("parallel", "arbitrary"))

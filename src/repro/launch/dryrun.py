@@ -25,9 +25,7 @@ import traceback
 import jax
 
 from repro.configs.registry import ALIASES, SHAPES, all_cells, get_config
-from repro.launch.hlo_analysis import (collective_stats,
-                                       cost_analysis_compat,
-                                       roofline_terms)
+from repro.launch.hlo_analysis import collective_stats, roofline_terms
 from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import build_cell
 from repro.models.common import active_param_count
@@ -217,7 +215,7 @@ def calibrate_cell(arch, sp, mesh, cfg, n_dev, seq_parallel=None,
                 out_shardings=cell.out_shardings,
                 donate_argnums=cell.donate_argnums,
             ).lower(*cell.args).compile()
-        cost = cost_analysis_compat(comp)
+        cost = comp.cost_analysis()
         coll = collective_stats(comp.as_text())
         return {
             "flops": float(cost.get("flops", 0.0)),
@@ -311,7 +309,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
         "alias_bytes": getattr(mem, "alias_size_in_bytes", None),
         "code_bytes": getattr(mem, "generated_code_size_in_bytes", None),
     }
-    cost = cost_analysis_compat(compiled)
+    cost = compiled.cost_analysis()
     flops_raw = float(cost.get("flops", 0.0))  # under-counts loop bodies
     bytes_raw = float(cost.get("bytes accessed", 0.0))
 

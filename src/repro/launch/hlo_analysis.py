@@ -23,17 +23,6 @@ PEAK_FLOPS = 197e12      # bf16 / chip
 HBM_BW = 819e9           # bytes/s / chip
 ICI_BW = 50e9            # bytes/s / link
 
-def cost_analysis_compat(compiled) -> Dict[str, float]:
-    """compiled.cost_analysis() across JAX versions.
-
-    0.4.x returns a single-element list of dicts; newer releases return
-    the dict directly.  Always yields a dict (empty when unavailable).
-    """
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,

@@ -42,6 +42,7 @@ import numpy as np
 
 from repro.configs.registry import get_config
 from repro.launch.batching import BatchingScheduler, Request
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import init_cache, init_lm_params, lm_decode_step
 
 N_CHANNELS = 2  # per-request telemetry: (entropy, max-logit)
@@ -367,6 +368,7 @@ def main(argv=None):
                     help="run the occupancy rebalancer every N ticks "
                          "(0: never; sharded gateway only)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     fmt = None
     if args.backend == "pallas-q":
@@ -381,6 +383,10 @@ def main(argv=None):
             pipeline_depth=args.pipeline_depth,
             block_c=args.block_c,
             shards=args.shards,
+            # one device per shard: the pool raises when the host has
+            # fewer devices than shards instead of stacking them on one
+            shard_devices=(jax.devices()[:args.shards]
+                           if args.shards > 1 else None),
             rebalance_every=args.rebalance_every,
             # depth > 1 only pipelines in the async loop
             measure_latency=args.pipeline_depth <= 1,

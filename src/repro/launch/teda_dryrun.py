@@ -21,11 +21,8 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.distributed import _local_shard_scan
-from repro.launch.hlo_analysis import (collective_stats,
-                                       cost_analysis_compat,
-                                       roofline_terms)
+from repro.launch.hlo_analysis import collective_stats, roofline_terms
 from repro.launch.mesh import make_production_mesh
-from repro.sharding.rules import shard_map_compat
 
 
 def run(multi_pod: bool, t_total: int, n_feat: int) -> dict:
@@ -36,12 +33,12 @@ def run(multi_pod: bool, t_total: int, n_feat: int) -> dict:
     import functools
     body = functools.partial(_local_shard_scan, m=3.0, axis_name=axes)
     from repro.core.teda import TedaOutput, TedaState
-    mapped = shard_map_compat(
+    mapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axes, None),),
         out_specs=(TedaState(k=P(), mean=P(), var=P()),
                    TedaOutput(*([P(axes)] * 6))),
-        check=False,
+        check_vma=False,
     )
     x = jax.ShapeDtypeStruct((t_total, n_feat), jnp.float32)
     with mesh:
@@ -49,7 +46,7 @@ def run(multi_pod: bool, t_total: int, n_feat: int) -> dict:
             mapped,
             in_shardings=(NamedSharding(mesh, P(axes, None)),),
         ).lower(x).compile()
-    cost = cost_analysis_compat(comp)
+    cost = comp.cost_analysis()
     coll = collective_stats(comp.as_text())
     mem = comp.memory_analysis()
     terms = roofline_terms(float(cost.get("flops", 0.0)),

@@ -1,5 +1,4 @@
 """Mesh/sharding rules + pipeline parallelism."""
-from repro.sharding.rules import (abstract_mesh, batch_spec, cache_spec,
-                                  dp_axes, make_mesh_compat, param_spec,
-                                  params_shardings,
+from repro.sharding.rules import (batch_spec, cache_spec, dp_axes,
+                                  param_spec, params_shardings,
                                   state_cache_shardings)

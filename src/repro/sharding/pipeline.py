@@ -18,8 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.sharding.rules import shard_map_compat
-
 
 def pipeline_forward(stage_fn: Callable, n_stages: int, axis: str = "pipe"):
     """Build a per-device pipelined forward for shard_map.
@@ -72,10 +70,10 @@ def make_pipelined(mesh: Mesh, stage_fn: Callable, n_stages: int,
                    axis: str = "pipe"):
     """jit-wrapped shard_map pipeline. stage_params stacked (S, ...)."""
     run = pipeline_forward(stage_fn, n_stages, axis)
-    mapped = shard_map_compat(
+    mapped = jax.shard_map(
         run, mesh=mesh,
         in_specs=(P(axis), P()),  # params sharded by stage, x replicated
         out_specs=P(),
-        check=False,
+        check_vma=False,
     )
     return jax.jit(mapped)

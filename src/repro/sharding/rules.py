@@ -44,60 +44,6 @@ RULE_FLAGS = {
 }
 
 
-def abstract_mesh(axis_sizes: Tuple[int, ...],
-                  axis_names: Tuple[str, ...]):
-    """Version-compatible jax.sharding.AbstractMesh constructor.
-
-    JAX 0.4.36+ takes a ((name, size), ...) shape_tuple; newer releases
-    take (axis_sizes, axis_names) positionally.  Spec-rule tests and
-    dry-runs construct device-free meshes through this shim so they work
-    on either signature.
-    """
-    import inspect
-
-    from jax.sharding import AbstractMesh
-
-    params = inspect.signature(AbstractMesh.__init__).parameters
-    if "shape_tuple" in params:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
-    return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-
-
-def make_mesh_compat(axis_shapes: Tuple[int, ...],
-                     axis_names: Tuple[str, ...]) -> Mesh:
-    """Version-compatible jax.make_mesh with Auto axis types.
-
-    jax.sharding.AxisType (and make_mesh's axis_types kwarg) only exist
-    on newer JAX; older releases are Auto-by-default.
-    """
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            axis_shapes, axis_names,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
-    return jax.make_mesh(axis_shapes, axis_names)
-
-
-def shard_map_compat(f, mesh, in_specs, out_specs, check: bool = False):
-    """Version-compatible shard_map.
-
-    Newer JAX exposes jax.shard_map with `check_vma`; 0.4.x has
-    jax.experimental.shard_map.shard_map with `check_rep`.
-    """
-    import inspect
-
-    if hasattr(jax, "shard_map"):
-        sm = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as sm
-    params = inspect.signature(sm).parameters
-    kw = {}
-    if "check_vma" in params:
-        kw["check_vma"] = check
-    elif "check_rep" in params:
-        kw["check_rep"] = check
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
 def make_channel_fanout(fn, mesh: Mesh, axis_name: str = "data"):
     """shard_map fan-out of an independent-channel stream processor.
 
@@ -114,8 +60,8 @@ def make_channel_fanout(fn, mesh: Mesh, axis_name: str = "data"):
     """
     vec = P(axis_name)
     row = P(None, axis_name)
-    return shard_map_compat(
-        fn, mesh=mesh,
+    return jax.shard_map(
+        fn, mesh=mesh, check_vma=False,
         in_specs=(row, vec, vec, vec, vec, vec),
         out_specs=((vec, vec, vec), (row, row)),
     )

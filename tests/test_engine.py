@@ -290,18 +290,16 @@ def test_sharded_fanout_single_device(backend):
     """mesh fan-out == plain processing (1-device mesh; the multi-device
     path is exercised by tests/test_distributed.py's forked runner)."""
     import jax
-    from repro.sharding.rules import make_mesh_compat
-    mesh = make_mesh_compat((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), (jax.sharding.AxisType.Auto,))
     x = _x(48, 4, seed=51)
     plain = _mk(4, backend)
     sharded = _mk(4, backend, mesh=mesh)
     o1, o2 = plain.process(x), sharded.process(x)
     np.testing.assert_array_equal(np.asarray(o1["outlier"]),
                                   np.asarray(o2["outlier"]))
-    del jax
 
 
 def test_fanout_capacity_divisibility():
-    from repro.sharding.rules import make_mesh_compat
-    mesh = make_mesh_compat((1,), ("data",))
+    import jax
+    mesh = jax.make_mesh((1,), ("data",), (jax.sharding.AxisType.Auto,))
     StreamEngine(4, "scan", mesh=mesh)  # divisible: fine

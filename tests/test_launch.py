@@ -59,10 +59,11 @@ def test_mini_dryrun_all_kinds():
         import jax
         from repro.configs.registry import ShapeSpec, get_config
         from repro.launch.specs import build_cell
+        from jax.sharding import AxisType
         from repro.launch.hlo_analysis import collective_stats, \
-            cost_analysis_compat, roofline_terms
-        from repro.sharding.rules import make_mesh_compat
-        mesh = make_mesh_compat((4, 2), ("data", "model"))
+            roofline_terms
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             (AxisType.Auto,) * 2)
         for arch in ("mixtral_8x7b", "zamba2_2p7b", "gemma2_2b"):
             cfg = get_config(arch).reduced()
             for kind, b, s in (("train", 8, 64), ("prefill", 8, 64),
@@ -75,7 +76,7 @@ def test_mini_dryrun_all_kinds():
                         out_shardings=cell.out_shardings,
                         donate_argnums=cell.donate_argnums,
                     ).lower(*cell.args).compile()
-                cost = cost_analysis_compat(comp)
+                cost = comp.cost_analysis()
                 assert float(cost.get("flops", 0)) > 0
                 stats = collective_stats(comp.as_text())
                 terms = roofline_terms(1e12, 1e9, stats["total_bytes"])
@@ -95,8 +96,8 @@ def test_pipeline_parallel_4stage():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import jax, jax.numpy as jnp, numpy as np
         from repro.sharding.pipeline import make_pipelined
-        from repro.sharding.rules import make_mesh_compat
-        mesh = make_mesh_compat((4,), ("pipe",))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((4,), ("pipe",), (AxisType.Auto,))
         # 4 affine stages; reference = composed application
         ws = jnp.asarray([[2.0], [0.5], [3.0], [1.0]])  # (S, 1) scales
         def stage(w, x):

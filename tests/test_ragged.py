@@ -250,3 +250,40 @@ def test_valid_lens_validation():
         eng.process(x, valid_lens=[-1, 2, 0])  # negative
     with pytest.raises(ValueError, match="scalar or"):
         eng.process(x, valid_lens=[1, 2])      # wrong width
+
+
+@pytest.mark.parametrize("c,block_t,want", [
+    (4096, 8, 0),       # the scheduler's block_t: one strip fits
+    (256, 256, 0),      # exactly the tile budget: one strip
+    (4096, 256, 256),   # the widest 128-multiple strip within budget
+    (1000, 128, 512),   # lanes padded to 1,024 first
+    (1152, 128, 384),   # 9 x 128 lanes: the widest strip dividing them
+    (4096, 512, 128),   # never narrower than one lane register
+])
+def test_default_block_c_fits_tile_budget(c, block_t, want):
+    from repro.kernels.ragged import TILE_ELEMS, norm_block_c
+    bc = norm_block_c(None, block_t, c, 128)
+    assert bc == want
+    if bc:
+        assert (-(-c // 128) * 128) % bc == 0
+        assert bc == 128 or block_t * bc <= TILE_ELEMS
+
+
+def test_explicit_block_c_is_kept_and_validated():
+    from repro.kernels.ragged import norm_block_c
+    assert norm_block_c(1024, 256, 4096, 128) == 1024
+    assert norm_block_c(0, 256, 4096, 128) == 0
+    with pytest.raises(ValueError, match="multiple of 128"):
+        norm_block_c(100, 256, 4096, 128)
+
+
+@pytest.mark.parametrize("platform,want", [("cpu", True), ("tpu", False),
+                                           ("gpu", None)])
+def test_default_interpret_only_on_cpu(monkeypatch, platform, want):
+    from repro.kernels import ragged
+    monkeypatch.setattr(ragged.jax, "default_backend", lambda: platform)
+    if want is None:  # no silent emulation on another accelerator
+        with pytest.raises(RuntimeError, match="'gpu'"):
+            ragged.default_interpret()
+    else:
+        assert ragged.default_interpret() is want

@@ -75,7 +75,10 @@ def test_lm_serve_demo_tiny():
     assert res["prefill_tok_s"] > 0 and res["decode_tok_s"] > 0
 
 
-def test_cli_streams_mode(capsys):
+def test_cli_streams_mode(capsys, monkeypatch, tmp_path):
+    # a set JAX_COMPILATION_CACHE_DIR leaves JAX's cache config alone,
+    # so the CLI does not turn the persistent cache on for this worker
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     main(["--mode", "streams", "--requests", "4", "--history", "16",
           "--live", "4", "--backend", "scan"])
     out = capsys.readouterr().out

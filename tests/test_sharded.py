@@ -286,6 +286,50 @@ def test_migration_carries_ensemble_aux_exactly():
     assert out_m.any()  # the burst at x[17] actually flagged
 
 
+def test_one_device_per_shard_pins_engine_state():
+    """devices= with one device per shard places each shard's state on
+    its device (no mesh, so the ensemble backend shards too): the state
+    stays committed there through a bucket resize and a migration, and
+    verdicts equal the unpinned pool's."""
+    import jax
+    dev = jax.devices()[0]
+    opts = dict(shards=2, buckets=(2, 4), block_t=8, interpret=True)
+    pinned = ShardedPool("ensemble", devices=[dev, dev], **opts)
+    plain = ShardedPool("ensemble", **opts)
+    rng = np.random.default_rng(9)
+    x = rng.normal(size=(16, 3)).astype(np.float32)
+    x[11, 1] += 25.0
+    outs = []
+    for pool in (pinned, plain):
+        for r in ("a", "b", "c"):
+            pool.acquire(r, shard=0)  # 3 streams: shard 0 grows to 4
+        pool.migrate("b", 1)
+        got = {}
+        for s in (0, 1):
+            cap = pool.shard_capacity(s)
+            chunk = np.zeros((16, cap), np.float32)
+            vl = np.zeros((cap,), np.int32)
+            for j, r in enumerate(("a", "b", "c")):
+                rs, slot = pool.lookup(r)
+                if rs == s:
+                    chunk[:, slot], vl[slot] = x[:, j], 16
+            out = np.asarray(pool.process_shard(s, chunk,
+                                                valid_lens=vl)["outlier"])
+            for j, r in enumerate(("a", "b", "c")):
+                rs, slot = pool.lookup(r)
+                if rs == s:
+                    got[r] = out[:, slot]
+        outs.append(got)
+    for r in ("a", "b", "c"):
+        np.testing.assert_array_equal(outs[0][r], outs[1][r])
+    assert outs[0]["b"].any()  # the burst actually flagged
+    for p in pinned.pools:
+        for eng in p._engines.values():
+            assert eng.device == dev
+            assert all(a.committed and a.devices() == {dev}
+                       for a in eng.state if a is not None)
+
+
 def test_migrate_to_full_shard_leaves_stream_in_place():
     pool = ShardedPool("scan", shards=2, buckets=(2,))
     pool.acquire("a", shard=0)

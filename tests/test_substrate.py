@@ -110,8 +110,7 @@ def test_checkpoint_elastic_resharding(tmp_path):
     mgr = CheckpointManager(str(tmp_path), async_save=False)
     state = {"x": jnp.arange(8.0)}
     mgr.save(1, state)
-    from repro.sharding.rules import make_mesh_compat
-    mesh = make_mesh_compat((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), (jax.sharding.AxisType.Auto,))
     sh = {"x": jax.NamedSharding(mesh, jax.sharding.PartitionSpec("data"))}
     restored, _ = mgr.restore(state, shardings=sh)
     assert restored["x"].sharding.is_equivalent_to(sh["x"], 1)
@@ -160,8 +159,9 @@ def test_batch_stats_shape():
 # ------------------------------------------------------- sharding rules --
 def test_param_spec_rules():
     from jax.sharding import PartitionSpec as P
-    from repro.sharding.rules import abstract_mesh, param_spec
-    mesh = abstract_mesh((16, 16), ("data", "model"))
+    from jax.sharding import AbstractMesh
+    from repro.sharding.rules import param_spec
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     # big 2D up-projection: FSDP in, TP out
     assert param_spec(mesh, "blocks_0/mlp/wi/w", (48, 8192, 22016)) == \
         P(None, "data", "model")
@@ -186,8 +186,9 @@ def test_param_spec_rules():
 
 def test_batch_and_cache_specs():
     from jax.sharding import PartitionSpec as P
-    from repro.sharding.rules import abstract_mesh, batch_spec, cache_spec
-    mesh = abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+    from jax.sharding import AbstractMesh
+    from repro.sharding.rules import batch_spec, cache_spec
+    mesh = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
     assert batch_spec(mesh, 256) == P(("pod", "data"), None)
     assert batch_spec(mesh, 16) == P("data", None)
     # decode cache: batch shardable
