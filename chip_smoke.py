@@ -72,35 +72,6 @@ def streams_of(x: np.ndarray, history: int):
             for i in range(x.shape[1])]
 
 
-class CompileWatch:
-    """Backend compile time and persistent-cache hits, from JAX's own
-    monitoring events, read per phase as deltas."""
-
-    def __init__(self):
-        import jax
-        self.compiles, self.compile_s, self.hits = 0, 0.0, 0
-
-        def on_duration(event, duration, **_):
-            if event == "/jax/core/compile/backend_compile_duration":
-                self.compiles += 1
-                self.compile_s += duration
-
-        def on_event(event, **_):
-            if event == "/jax/compilation_cache/cache_hits":
-                self.hits += 1
-
-        jax.monitoring.register_event_duration_secs_listener(on_duration)
-        jax.monitoring.register_event_listener(on_event)
-
-    def mark(self):
-        return (self.compiles, self.compile_s, self.hits)
-
-    def since(self, mark):
-        return {"compiles": self.compiles - mark[0],
-                "compile_s": self.compile_s - mark[1],
-                "cache_hits": self.hits - mark[2]}
-
-
 def gateway(x, history, backend, *, interpret, **opts):
     """Serve the fleet; returns (result, (T, n) verdict stream, (T, n)
     native ecc / detector-bit stream)."""
@@ -265,6 +236,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     from repro.launch.compile_cache import use_compile_cache
+    from repro.obs import compile_watch
     import jax
     cache = use_compile_cache()
     devs = jax.devices()
@@ -279,7 +251,7 @@ def main(argv=None) -> int:
               file=sys.stderr)
         return 1
     print(f"[smoke] compile cache {cache}", flush=True)
-    watch = CompileWatch()
+    watch = compile_watch()
     history = FLEET["history"]
     x = make_fleet(args.streams, history, FLEET["live"], args.seed)
     t0 = time.perf_counter()

@@ -34,7 +34,7 @@ from repro.engine.backends import get_backend
 from repro.engine.state import (EngineState, engine_attach, engine_detach,
                                 engine_init, engine_process, engine_reset,
                                 slot_mask)
-from repro.obs import NULL_TRACER, MetricsRegistry, auto_name
+from repro.obs import MetricsRegistry, auto_name
 
 __all__ = ["StreamEngine"]
 
@@ -57,7 +57,7 @@ class StreamEngine:
                  block_c: Optional[int] = None,
                  interpret: Optional[bool] = None, lane_pad: int = 128,
                  mesh=None, axis_name: str = "data", device=None,
-                 auto_attach: bool = True, registry=None, tracer=None,
+                 auto_attach: bool = True, registry=None,
                  name: Optional[str] = None, **backend_opts):
         if mesh is not None and device is not None:
             raise ValueError("pass a mesh (channel fan-out) or a device "
@@ -66,12 +66,10 @@ class StreamEngine:
         self.device = device
         self.default_m = float(m)
         # observability (repro.obs): process-call / samples-retired /
-        # program-compile counters, labelled by engine instance; the
-        # tracer records a compile instant when a new (capacity, T)
-        # program shape is first executed
+        # program-shape counters, labelled by engine instance (JAX's
+        # own compile events are counted by `repro.obs.compile_watch`)
         self.registry = (MetricsRegistry() if registry is None
                          else registry)
-        self.tracer = NULL_TRACER if tracer is None else tracer
         self.name = auto_name("engine") if name is None else str(name)
         lbl = {"engine": self.name}
         self._c_calls = self.registry.counter(
@@ -323,9 +321,6 @@ class StreamEngine:
         if t_key not in self._t_shapes:
             self._t_shapes.add(t_key)
             self._c_programs.inc()
-            if self.tracer.enabled:
-                self.tracer.instant("engine.compile", engine=self.name,
-                                    capacity=self.capacity, t=t_key)
         self._c_calls.inc()
         if had_vlens and vc is None:
             return

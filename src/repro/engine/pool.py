@@ -62,9 +62,10 @@ class SlotPool:
         self._opts = dict(engine_opts, m=m)
         self._engines: dict[int, StreamEngine] = {}
         self._bucket = self.buckets[0]
-        # observability (repro.obs): the registry/tracer are shared
-        # with every per-bucket engine (engine series are labelled
-        # `<pool>/capN`), so one snapshot covers the whole pool
+        # observability (repro.obs): the registry is shared with every
+        # per-bucket engine (engine series are labelled `<pool>/capN`),
+        # so one snapshot covers the whole pool; the tracer records an
+        # `acquire` span per acquisition and a `pool.resize` instant
         self.registry = (MetricsRegistry() if registry is None
                          else registry)
         self.tracer = NULL_TRACER if tracer is None else tracer
@@ -95,7 +96,6 @@ class SlotPool:
             eng = StreamEngine(bucket, self.backend_name,
                                auto_attach=False,
                                registry=self.registry,
-                               tracer=self.tracer,
                                name=f"{self.name}/cap{bucket}",
                                **self._opts)
             self._engines[bucket] = eng
@@ -202,7 +202,21 @@ class SlotPool:
         scheduler's backpressure signal.  `detectors` / `vote` select
         the new tenants' detector subset and vote mode under the
         ensemble backend (`StreamEngine.attach`).
+
+        Traced as one `acquire` span (its active-mask fetch waits for
+        the call in flight; `resized` says whether it re-padded).
         """
+        if not self.tracer.enabled:
+            return self._acquire(n, m, detectors, vote)
+        bucket = self._bucket
+        with self.tracer.span("acquire", device=True, n=n,
+                              pool=self.name) as span:
+            try:
+                return self._acquire(n, m, detectors, vote)
+            finally:
+                span.set(resized=self._bucket != bucket)
+
+    def _acquire(self, n, m, detectors, vote) -> np.ndarray:
         act = np.asarray(self.engine.state.active)
         need = int(act.sum()) + n
         if need > self._bucket:

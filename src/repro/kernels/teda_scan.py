@@ -246,6 +246,7 @@ def teda_pallas_call(x: jnp.ndarray, scal: jnp.ndarray, vlen: jnp.ndarray,
             dimension_semantics=("parallel", "arbitrary"))
     return pl.pallas_call(
         kernel,
+        name="teda_scan",  # the custom call's name in a device trace
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),  # scal (1,)

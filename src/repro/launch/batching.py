@@ -89,7 +89,7 @@ import numpy as np
 
 from repro.engine import PoolFull, ShardedPool, SlotPool
 from repro.obs import (EventBus, LATENCY_MS_BUCKETS, MetricsRegistry,
-                       NULL_TRACER, TICK_BUCKETS, auto_name)
+                       NULL_SPAN, NULL_TRACER, TICK_BUCKETS, auto_name)
 
 __all__ = ["Request", "RequestStats", "BatchingScheduler",
            "EvictedRequest"]
@@ -414,6 +414,14 @@ class BatchingScheduler:
         self._c_flags = reg.counter(
             "sched_flags_total", "outlier verdicts raised",
             ("sched",)).labels(**lbl)
+        self._c_h2d = reg.counter(
+            "sched_h2d_bytes_total",
+            "bytes of chunks and valid lengths sent to the device",
+            ("sched",)).labels(**lbl)
+        self._c_d2h = reg.counter(
+            "sched_d2h_bytes_total",
+            "bytes of call outputs fetched from the device",
+            ("sched",)).labels(**lbl)
         self._g_inflight = reg.gauge(
             "sched_inflight_calls",
             "dispatched fused calls not yet host-fetched",
@@ -557,6 +565,18 @@ class BatchingScheduler:
 
     # --------------------------------------------------------- the tick
     def _admit(self, events: dict) -> None:
+        """Admit what the class queues allow (`_admit_round`), traced
+        as one `admission` span when a queue holds a request."""
+        if not (self.tracer.enabled and self.queued_total):
+            self._admit_round(events)
+            return
+        before = len(events["admitted"])
+        with self.tracer.span("admission", device=True,
+                              tick=self.tick_no) as span:
+            self._admit_round(events)
+            span.set(admitted=len(events["admitted"]) - before)
+
+    def _admit_round(self, events: dict) -> None:
         """Weighted-deficit round robin across the class queues.
 
         Every pass tops each backlogged class's deficit up by its
@@ -652,41 +672,46 @@ class BatchingScheduler:
         valid-length vector; everyone else is suspended at vlen=0.
         Decode-only ticks (every member's pending <= decode_t) ride
         the short cached (decode_t, C) program instead of the full
-        chunk."""
-        cap = (self.pool.shard_capacity(shard) if self._sharded
-               else self.pool.capacity)
-        t_len = self.chunk_t
-        if all(r.avail <= self.decode_t for r in members):
-            t_len = self.decode_t
-            self._c_short.inc()
-        x = np.zeros((t_len, cap), np.float32)
-        vlens = np.zeros((cap,), np.int32)
-        mem = []
-        for run in members:
-            n = min(run.avail, t_len)
-            x[:n, run.slot] = run.take(n)
-            vlens[run.slot] = n
-            run.inflight += 1
-            mem.append((run, run.slot, n))
+        chunk.  Traced as an `assemble` span (the chunk and
+        valid-length vector built on host) then a `dispatch` span (the
+        pool and engine call)."""
+        tracer = self.tracer
+        with (tracer.span("assemble", device=True, tick=self.tick_no,
+                          slots=len(members), shard=shard)
+              if tracer.enabled else NULL_SPAN) as span:
+            cap = (self.pool.shard_capacity(shard) if self._sharded
+                   else self.pool.capacity)
+            t_len = self.chunk_t
+            if all(r.avail <= self.decode_t for r in members):
+                t_len = self.decode_t
+                self._c_short.inc()
+            span.set(t=t_len)
+            x = np.zeros((t_len, cap), np.float32)
+            vlens = np.zeros((cap,), np.int32)
+            mem = []
+            for run in members:
+                n = min(run.avail, t_len)
+                x[:n, run.slot] = run.take(n)
+                vlens[run.slot] = n
+                run.inflight += 1
+                mem.append((run, run.slot, n))
         self._c_calls.inc()
-        span = (self.tracer.span(
-                    "dispatch", device=True, tick=self.tick_no,
-                    t=t_len, slots=len(mem), shard=shard,
-                    samples=int(sum(n for _, _, n in mem)))
-                if self.tracer.enabled else None)
-        if span is not None:
-            span.__enter__()
-        t0 = time.perf_counter()
-        if self._sharded:
-            out = self.pool.process_shard(shard, x, valid_lens=vlens)
-        else:
-            out = self.pool.process(x, valid_lens=vlens)
-        sync_wall = None
-        if self.measure_latency:
-            jax.block_until_ready(out["ecc"])
-            sync_wall = time.perf_counter() - t0
-        if span is not None:
-            span.__exit__(None, None, None)
+        h2d = x.nbytes + vlens.nbytes
+        self._c_h2d.inc(h2d)
+        with (tracer.span("dispatch", device=True, tick=self.tick_no,
+                          t=t_len, slots=len(mem), shard=shard,
+                          samples=int(sum(n for _, _, n in mem)),
+                          h2d_bytes=h2d)
+              if tracer.enabled else NULL_SPAN):
+            t0 = time.perf_counter()
+            if self._sharded:
+                out = self.pool.process_shard(shard, x, valid_lens=vlens)
+            else:
+                out = self.pool.process(x, valid_lens=vlens)
+            sync_wall = None
+            if self.measure_latency:
+                jax.block_until_ready(out["ecc"])
+                sync_wall = time.perf_counter() - t0
         self._inflight.append(_InFlight(
             out, mem, t_len, self.tick_no, t0, sync_wall,
             shard=shard if self._sharded else None))
@@ -701,26 +726,41 @@ class BatchingScheduler:
         flagged rids are deferred into the next tick's events.
         Every member's verdict streams on the event bus here — this is
         the retirement moment, the earliest a verdict exists on host.
+        Traced as a `retire` span (the fetch, with the bytes it moves)
+        then an `account` span (everything after it).
         """
+        out = inf.out
         # the ensemble backend's "ecc" stream is the per-detector flag
         # bitmask — fetched even with collect=False, it feeds the
-        # per-detector counters below
+        # per-detector counters; its per-detector (K, T, C) float
+        # score streams ride the same fetch (RequestStats /
+        # chunk_retired telemetry)
         want_ecc = self.collect or self._ensemble
-        if self.tracer.enabled:
-            with self.tracer.span("retire", tick=self.tick_no,
-                                  dispatch_tick=inf.tick, t=inf.t_len,
-                                  slots=len(inf.members)):
-                outlier = np.asarray(inf.out["outlier"])
-                ecc = (np.asarray(inf.out["ecc"]) if want_ecc
-                       else None)
-        else:
-            outlier = np.asarray(inf.out["outlier"])
-            ecc = np.asarray(inf.out["ecc"]) if want_ecc else None
-        # the ensemble's per-detector (K, T, C) float score streams
-        # ride the same fetch — per-request sums feed RequestStats /
-        # chunk_retired telemetry
-        scores = (np.asarray(inf.out["scores"])
-                  if self._ensemble and "scores" in inf.out else None)
+        want_scores = self._ensemble and "scores" in out
+        # sizes of the output arrays, read before the fetch: no sync
+        d2h = (out["outlier"].nbytes
+               + (out["ecc"].nbytes if want_ecc else 0)
+               + (out["scores"].nbytes if want_scores else 0))
+        self._c_d2h.inc(d2h)
+        tracer = self.tracer
+        with (tracer.span("retire", device=True, tick=self.tick_no,
+                          dispatch_tick=inf.tick, t=inf.t_len,
+                          slots=len(inf.members), d2h_bytes=d2h)
+              if tracer.enabled else NULL_SPAN):
+            outlier = np.asarray(out["outlier"])
+            ecc = np.asarray(out["ecc"]) if want_ecc else None
+            scores = np.asarray(out["scores"]) if want_scores else None
+        with (tracer.span("account", device=True, tick=self.tick_no,
+                          slots=len(inf.members))
+              if tracer.enabled else NULL_SPAN):
+            self._account(inf, outlier, ecc, scores, events)
+
+    def _account(self, inf: _InFlight, outlier: np.ndarray,
+                 ecc: Optional[np.ndarray], scores: Optional[np.ndarray],
+                 events: Optional[dict]) -> None:
+        """Account one fetched call: the call log and wall histogram,
+        then per member its telemetry, flag counters and
+        `chunk_retired` event."""
         wall = (inf.sync_wall if inf.sync_wall is not None
                 else time.perf_counter() - inf.t0)
         retired = int(sum(n for _, _, n in inf.members))
@@ -797,22 +837,26 @@ class BatchingScheduler:
         """Retire every in-flight call (the consume-side sync)."""
         if not self._inflight:
             return
-        if self.tracer.enabled:
-            with self.tracer.span("flush", tick=self.tick_no,
-                                  calls=len(self._inflight)):
-                while self._inflight:
-                    self._retire(self._inflight.popleft(), events)
-            return
-        while self._inflight:
-            self._retire(self._inflight.popleft(), events)
+        with (self.tracer.span("flush", tick=self.tick_no,
+                               calls=len(self._inflight))
+              if self.tracer.enabled else NULL_SPAN):
+            while self._inflight:
+                self._retire(self._inflight.popleft(), events)
 
     def step(self) -> dict:
         """One scheduler tick; returns {admitted, flagged, completed}.
 
         In the async loop, `flagged` events surface on the tick whose
-        retirement fetched them — one tick after dispatch.
+        retirement fetched them — one tick after dispatch.  Traced as
+        one `tick` span holding a span per phase.
         """
         self._c_ticks.inc()
+        if not self.tracer.enabled:
+            return self._tick()
+        with self.tracer.span("tick", device=True, tick=self.tick_no):
+            return self._tick()
+
+    def _tick(self) -> dict:
         events: dict = {"admitted": [], "flagged": [], "completed": []}
         if self._deferred_flagged:
             events["flagged"].extend(self._deferred_flagged)
@@ -874,6 +918,16 @@ class BatchingScheduler:
             # completion consumes results: sync the tail call now so
             # done_tick/telemetry are final the tick the stream drains
             self._flush(events)
+        if done:
+            with (self.tracer.span("complete", device=True,
+                                   tick=self.tick_no, completed=len(done))
+                  if self.tracer.enabled else NULL_SPAN):
+                self._complete(done, events)
+        return events
+
+    def _complete(self, done: List[str], events: dict) -> None:
+        """Release the drained requests' slots and record them done,
+        evicting the oldest finished records past `keep_finished`."""
         for rid in done:
             run = self.runs.pop(rid)
             run.phase = DONE
@@ -899,7 +953,6 @@ class BatchingScheduler:
                 self.stats_by_rid.pop(old, None)
                 self._note_evicted(old)
                 self.events.publish("evicted", self.tick_no, old)
-        return events
 
     def _rebalance(self) -> None:
         """Run the pool's occupancy rebalancer and mirror the moves
@@ -1052,6 +1105,8 @@ class BatchingScheduler:
                "pipeline_depth": self.pipeline_depth,
                "short_ticks": self.short_ticks,
                "chunk_latency": lat, "classes": classes,
+               "h2d_bytes": int(self._c_h2d.value),
+               "d2h_bytes": int(self._c_d2h.value),
                "programs": self.pool.programs(),
                "pool": self.pool.stats()}
         if self._sharded:

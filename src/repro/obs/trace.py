@@ -1,13 +1,14 @@
 """Tick-level tracer: bounded ring buffer of span events, Chrome
 trace-event JSON export.
 
-The serving tick is a pipeline (admit -> dispatch -> retire -> flush,
-with pool resizes and program compiles as out-of-band events); this
-records it as spans with monotonic timestamps and tick/rid/slot
-attribution, in a preallocated ring buffer so a forever-running
-gateway traces at O(capacity) memory.  `to_chrome_trace()` emits the
-Chrome trace-event JSON that Perfetto (ui.perfetto.dev) and
-`chrome://tracing` open directly.
+The serving tick is a pipeline (`BatchingScheduler.step()`: admission,
+assemble, dispatch, retire, account, complete, with pool resizes and
+JAX compiles as out-of-band events); this records it as spans with
+monotonic timestamps and tick/rid/slot attribution, in a ring buffer
+that grows to `capacity` and then overwrites its oldest events, so a
+forever-running gateway traces at O(capacity) memory.
+`to_chrome_trace()` emits the Chrome trace-event JSON that Perfetto
+(ui.perfetto.dev) and `chrome://tracing` open directly.
 
 Off by default and zero-cost when disabled: components hold the
 module's `NULL_TRACER` singleton (``enabled = False``, no-op
@@ -28,7 +29,9 @@ import threading
 import time
 from typing import List, Optional
 
-__all__ = ["TickTracer", "NullTracer", "NULL_TRACER"]
+from repro.obs.compiles import compile_watch
+
+__all__ = ["TickTracer", "NullTracer", "NULL_TRACER", "NULL_SPAN"]
 
 try:  # optional pass-through to device traces
     from jax.profiler import TraceAnnotation as _TraceAnnotation
@@ -47,8 +50,13 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **args) -> None:
+        return None
 
-_NULL_SPAN = _NullSpan()
+
+#: the shared no-op span: ``with (tracer.span(...) if tracer.enabled
+#: else NULL_SPAN):`` keeps a disabled site at one attribute check
+NULL_SPAN = _NullSpan()
 
 
 class NullTracer:
@@ -61,7 +69,7 @@ class NullTracer:
     enabled = False
 
     def span(self, name: str, device: bool = False, **args):
-        return _NULL_SPAN
+        return NULL_SPAN
 
     def instant(self, name: str, **args) -> None:
         return None
@@ -96,6 +104,10 @@ class _Span:
         self._t0 = time.perf_counter()
         return self
 
+    def set(self, **args) -> None:
+        """Add arguments known only once the span's work is done."""
+        self._args.update(args)
+
     def __exit__(self, *exc):
         t1 = time.perf_counter()
         if self._ann is not None:
@@ -116,8 +128,15 @@ class TickTracer:
 
     `capacity` bounds memory: past it the oldest events are
     overwritten (`dropped` counts the overwrites).  Timestamps are
-    `time.perf_counter()` microseconds relative to construction —
-    monotonic, shared by every component handed this tracer.
+    `time.perf_counter()` microseconds relative to `origin`, a
+    `perf_counter()` value taken as the constructor's last act —
+    monotonic, shared by every component handed this tracer; an
+    event's host-clock time is ``origin + ts * 1e-6``.  Construction
+    is O(1) whatever the capacity (the ring grows as it fills), so a
+    caller timing the constructor finds `origin` at its end.
+
+    Every enabled tracer also receives JAX's backend compiles as
+    `compile` spans (`repro.obs.compiles`).
     """
 
     enabled = True
@@ -128,12 +147,13 @@ class TickTracer:
             raise ValueError(f"capacity must be >= 1: {capacity}")
         self.capacity = int(capacity)
         self._annotate_device = bool(annotate_device)
-        self._buf: List[Optional[dict]] = [None] * self.capacity
+        self._buf: List[dict] = []
         self._head = 0          # next write position
         self.total = 0          # events ever recorded
-        self._t0 = time.perf_counter()
         self._pid = os.getpid()
         self._lock = threading.Lock()
+        compile_watch().attach(self)
+        self.origin = time.perf_counter()
 
     # ------------------------------------------------------ recording
     def _record(self, name: str, ph: str, t_start: float, *,
@@ -141,13 +161,16 @@ class TickTracer:
                 args: Optional[dict] = None) -> None:
         ev = {"name": name, "ph": ph, "pid": self._pid,
               "tid": threading.get_ident(),
-              "ts": (t_start - self._t0) * 1e6}
+              "ts": (t_start - self.origin) * 1e6}
         if dur_s is not None:
             ev["dur"] = dur_s * 1e6
         if args:
             ev["args"] = args
         with self._lock:
-            self._buf[self._head] = ev
+            if len(self._buf) < self.capacity:
+                self._buf.append(ev)
+            else:
+                self._buf[self._head] = ev
             self._head = (self._head + 1) % self.capacity
             self.total += 1
 
@@ -160,6 +183,12 @@ class TickTracer:
     def instant(self, name: str, **args) -> None:
         """Record a zero-duration instant event."""
         self._record(name, "i", time.perf_counter(), args=args)
+
+    def complete(self, name: str, start: float, end: float,
+                 **args) -> None:
+        """Record a duration span whose `perf_counter()` start and end
+        are already known (work timed by someone else)."""
+        self._record(name, "X", start, dur_s=end - start, args=args)
 
     # ------------------------------------------------------ inspection
     def __len__(self) -> int:
@@ -175,7 +204,7 @@ class TickTracer:
         wraparound)."""
         with self._lock:
             if self.total < self.capacity:
-                return [e for e in self._buf[:self._head]]
+                return list(self._buf)
             return (self._buf[self._head:] + self._buf[:self._head])
 
     def to_chrome_trace(self) -> dict:

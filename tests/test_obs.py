@@ -9,10 +9,15 @@ Chrome trace JSON; and the event bus must stream verdicts in
 retirement order, bit-exact (Q path) with what `results()` returns
 after the fact.  The drain-flush regression test closes the loop: a
 bare `drain()` (no intervening `results()`/`telemetry()` reads) must
-leave nothing in flight and all telemetry complete.
+leave nothing in flight and all telemetry complete.  A traced
+scheduler nests one span per phase inside each `tick`, its transfer
+byte counts agree with what it sends and fetches, tracing changes no
+verdict, and a fresh jit shows as exactly one `compile` span.
 """
 import json
+import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -20,7 +25,8 @@ from repro.fixedpoint import QFormat
 from repro.launch.batching import BatchingScheduler, Request
 from repro.launch.serve import serve_streams
 from repro.obs import (EventBus, LATENCY_MS_BUCKETS, MetricsRegistry,
-                       NULL_TRACER, TickTracer, get_registry)
+                       NULL_TRACER, TickTracer, compile_watch,
+                       get_registry)
 
 FMT = QFormat(32, 20)
 
@@ -177,6 +183,49 @@ def test_chrome_trace_schema():
     assert inst["ph"] == "i" and "dur" not in inst
 
 
+def test_tracer_constructs_in_constant_time():
+    """The ring grows as it fills, so even a 2^20-event tracer builds
+    in well under a millisecond, and its clock origin is taken inside
+    the constructor's interval (at its end)."""
+    best = float("inf")
+    for _ in range(5):
+        t0 = time.perf_counter()
+        tr = TickTracer(capacity=1 << 20)
+        t1 = time.perf_counter()
+        assert t0 <= tr.origin <= t1
+        best = min(best, t1 - t0)
+    assert best < 1e-3
+    assert len(tr) == 0 and tr.events() == [] and tr.dropped == 0
+
+
+def test_fresh_jit_records_one_compile_span():
+    """JAX's backend-compile event becomes one `compile` span in every
+    enabled tracer and one increment of the global counters; a second
+    call of the same program compiles nothing."""
+    tr = TickTracer(capacity=64)
+    watch = compile_watch()
+    reg = get_registry()
+    n0, s0 = watch.compiles, watch.compile_s
+    fam0 = reg.get("jax_compiles_total").value
+    f = jax.jit(lambda x: x * 3.0 + 1.0)
+    x = np.ones((7, 13), np.float32)
+    t0 = time.perf_counter()
+    jax.block_until_ready(f(x))
+    t1 = time.perf_counter()
+    spans = [e for e in tr.events() if e["name"] == "compile"]
+    assert len(spans) == 1
+    assert watch.compiles == n0 + 1
+    assert reg.get("jax_compiles_total").value == fam0 + 1
+    sp = spans[0]
+    assert sp["ph"] == "X" and "lambda" in sp["args"]["fun"]
+    assert sp["dur"] * 1e-6 == pytest.approx(watch.compile_s - s0)
+    start = tr.origin + sp["ts"] * 1e-6
+    assert t0 <= start and start + sp["dur"] * 1e-6 <= t1
+    jax.block_until_ready(f(x))
+    assert len([e for e in tr.events() if e["name"] == "compile"]) == 1
+    assert watch.compiles == n0 + 1
+
+
 def test_null_tracer_is_inert():
     assert NULL_TRACER.enabled is False
     with NULL_TRACER.span("anything", tick=1):
@@ -316,6 +365,102 @@ def test_trace_spans_reconcile_with_metrics():
     assert s["ticks"] == sched.tick_no
     assert s["completed"] == len(specs)
     assert s["chunk_latency"]["calls"] == len(sched.call_log)
+
+
+PHASES = ("assemble", "dispatch", "retire", "account")
+
+
+@pytest.mark.parametrize("shards", [1, 2], ids=["pool", "sharded"])
+def test_tick_spans_nest_every_phase(shards):
+    """One `tick` span per `step()`; every other span of the tick lies
+    inside its tick on the same thread; each fused call has one span
+    of each per-call phase; admission, acquisition and completion
+    spans account for every request."""
+    specs = _specs(5, seed=7)
+    tr = TickTracer(capacity=1 << 14)
+    sched = BatchingScheduler("scan", buckets=(2, 4), chunk_t=4,
+                              shards=shards, tracer=tr)
+    _run_workload(sched, specs)
+    evs = [e for e in tr.events() if e["ph"] == "X"]
+    ticks = [e for e in evs if e["name"] == "tick"]
+    assert [e["args"]["tick"] for e in ticks] == list(
+        range(1, sched.tick_no + 1))
+    eps = 1e-3  # us: ts and dur are rounded separately
+    for e in evs:
+        # compiles are JAX's, wherever it compiles (construction too)
+        if e["name"] in ("tick", "compile"):
+            continue
+        holder = [t for t in ticks if t["tid"] == e["tid"]
+                  and t["ts"] - eps <= e["ts"]
+                  and e["ts"] + e["dur"] <= t["ts"] + t["dur"] + eps]
+        assert len(holder) == 1, e
+        if "tick" in e.get("args", {}):
+            assert e["args"]["tick"] == holder[0]["args"]["tick"]
+    calls = int(sched._c_calls.value)
+    for name in PHASES:
+        assert len([e for e in evs if e["name"] == name]) == calls > 0
+    admission = [e for e in evs if e["name"] == "admission"]
+    assert sum(e["args"]["admitted"] for e in admission) == len(specs)
+    acquire = [e for e in evs if e["name"] == "acquire"]
+    refused = sum(ch.value for _, ch in
+                  sched.registry.get("pool_full_total").series())
+    assert len(acquire) == len(specs) + refused
+    assert all(e["args"]["n"] == 1 and "resized" in e["args"]
+               for e in acquire)
+    assert any(e["args"]["resized"] for e in acquire)  # 2 -> 4 slots
+    complete = [e for e in evs if e["name"] == "complete"]
+    assert sum(e["args"]["completed"] for e in complete) == len(specs)
+
+
+@pytest.mark.parametrize("backend,collect", [("scan", True),
+                                             ("pallas-q", False)])
+def test_transfer_bytes_match_what_moves(backend, collect):
+    """`h2d_bytes` on the dispatch spans and `d2h_bytes` on the retire
+    spans sum to the bytes handed to the pool and fetched from its
+    outputs, and to the scheduler's counters."""
+    specs = _specs(4, seed=9)
+    tr = TickTracer(capacity=1 << 14)
+    sched = BatchingScheduler(backend, fmt=FMT, buckets=(2, 4),
+                              chunk_t=4, collect=collect, tracer=tr)
+    sent, fetched = [0], [0]
+    process = sched.pool.process
+
+    def watched(x, active=None, valid_lens=None):
+        out = process(x, active=active, valid_lens=valid_lens)
+        sent[0] += x.nbytes + valid_lens.nbytes
+        fetched[0] += out["outlier"].nbytes + (
+            out["ecc"].nbytes if collect else 0)
+        return out
+
+    sched.pool.process = watched
+    _run_workload(sched, specs)
+    evs = tr.events()
+    h2d = sum(e["args"]["h2d_bytes"] for e in evs
+              if e["name"] == "dispatch")
+    d2h = sum(e["args"]["d2h_bytes"] for e in evs
+              if e["name"] == "retire")
+    s = sched.stats()
+    assert h2d == sent[0] == s["h2d_bytes"] > 0
+    assert d2h == fetched[0] == s["d2h_bytes"] > 0
+    assert s["h2d_bytes"] == int(sched.registry.get(
+        "sched_h2d_bytes_total").labels(sched=sched.name).value)
+
+
+def test_tracing_changes_no_verdict():
+    """Verdicts (Q path, bit-exact) and flag counts are identical with
+    the disabled `NULL_TRACER` and with a recording tracer."""
+    specs = _specs(4, seed=13)
+    runs = []
+    for tracer in (NULL_TRACER, TickTracer(capacity=1 << 14)):
+        sched = BatchingScheduler("pallas-q", fmt=FMT, buckets=(2, 4),
+                                  chunk_t=4, collect=True, tracer=tracer)
+        _run_workload(sched, specs)
+        runs.append({rid: sched.results(rid) for rid in specs})
+    for rid in specs:
+        for key in ("ecc", "outlier"):
+            np.testing.assert_array_equal(runs[0][rid][key],
+                                          runs[1][rid][key],
+                                          err_msg=f"{rid} {key}")
 
 
 def test_drain_flushes_everything_without_reads():
