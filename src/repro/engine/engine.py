@@ -31,12 +31,25 @@ import numpy as np
 
 from repro.core.teda import TedaState
 from repro.engine.backends import get_backend
-from repro.engine.state import (EngineState, engine_attach, engine_detach,
-                                engine_init, engine_process, engine_reset,
-                                slot_mask)
+from repro.engine.state import (EngineState, engine_init, engine_process,
+                                engine_reset, slot_mask)
 from repro.obs import MetricsRegistry, auto_name
 
 __all__ = ["StreamEngine"]
+
+
+@jax.jit
+def _settle(state: EngineState, touched, active) -> EngineState:
+    """Every attach, detach and reset since the state was last read, in
+    one update.  Each of the three zeroes its slots (`engine_reset`)
+    and leaves their active bit where the host mirror has it, so the
+    touched slots end zeroed with the mirror's bit: the state of the
+    calls one by one, bit for bit.  One compiled program per state
+    signature (capacity, dtype, aux rows, placement); the two host
+    masks are its transfers.  No donation: a resize or a migration
+    reads the old state after it."""
+    return engine_reset(state, touched)._replace(
+        active=jnp.where(touched, active, state.active))
 
 
 class StreamEngine:
@@ -83,10 +96,13 @@ class StreamEngine:
             "engine_programs_compiled_total",
             "distinct (capacity, T) program shapes executed",
             ("engine",)).labels(**lbl)
-        # host mirror of the active-slot count, keyed by the identity
-        # of state.active (replaced by attach/detach/reset/resize):
-        # metrics never force an extra device fetch per call
-        self._active_cache = (None, 0)
+        # the host mirror of state.active (`active_mask`) is read back
+        # from the device only after a state assigned from outside
+        self._c_active_fetches = self.registry.counter(
+            "engine_active_fetches_total",
+            "host mirror of the active mask read back from the device "
+            "(after a state assigned from outside)",
+            ("engine",)).labels(**lbl)
         # block_c tiles the kernel grid's channel axis into parallel
         # strips (multi-core TPU scaling at wide capacity); extra
         # keyword options flow to the backend factory untouched (e.g.
@@ -105,6 +121,8 @@ class StreamEngine:
                 "backend (the aux state axis is not sharded)")
         self.state = engine_init(self.capacity, self.backend.state_dtype,
                                  active=auto_attach, aux_rows=n_aux)
+        self._active_host = self._frozen(
+            np.full((self.capacity,), bool(auto_attach)))
         if self._ensemble:
             self._det_names = tuple(self.backend.detectors)
             self._det_w = np.broadcast_to(
@@ -152,15 +170,51 @@ class StreamEngine:
 
     @property
     def state(self) -> EngineState:
+        """The packed device state, with every slot update so far."""
+        if self._touched is not None:
+            self._state = _settle(self._state, self._touched,
+                                  self._active_host)
+            self._touched = None
         return self._state
 
     @state.setter
     def state(self, st: EngineState) -> None:
         # a pinned engine re-commits every state it is handed (resizes
         # and migrations build theirs from host arrays), so its jitted
-        # calls keep following the state onto its device
+        # calls keep following the state onto its device.  A state from
+        # outside may hold any mask: the host mirror is read back from
+        # it once, when next needed.
         self._state = (st if self.device is None
                        else jax.device_put(st, self.device))
+        self._active_host = None
+        self._touched = None
+
+    @property
+    def active_mask(self) -> np.ndarray:
+        """Host mirror of `state.active`, a read-only (capacity,) bool
+        array.  attach/detach/reset set it and `process` keeps the mask
+        itself, so slot admin and per-call metrics never wait on the
+        device; only a state assigned from outside is read back, once
+        (`engine_active_fetches_total`)."""
+        if self._active_host is None:
+            self._active_host = self._frozen(np.asarray(self._state.active))
+            self._c_active_fetches.inc()
+        return self._active_host
+
+    @staticmethod
+    def _frozen(mask: np.ndarray) -> np.ndarray:
+        mask = np.array(mask, bool)
+        mask.flags.writeable = False
+        return mask
+
+    def _stage(self, mask: np.ndarray, active: np.ndarray) -> None:
+        """Record a slot update on the host: zero the `mask` slots and
+        give the mirror `active`.  The device sees it when the state is
+        next read (`process`, a resize, a migration), folded with every
+        other update since into one dispatch (`_settle`)."""
+        self._touched = (np.array(mask, bool) if self._touched is None
+                         else self._touched | mask)
+        self._active_host = self._frozen(active)
 
     # ------------------------------------------------------ slot admin
     def attach(self, slots=None, n: Optional[int] = None, *,
@@ -181,7 +235,7 @@ class StreamEngine:
         the backend's) — see `set_detectors`.  Both raise on a
         non-ensemble backend.
         """
-        occupied = np.asarray(self.state.active)
+        occupied = self.active_mask
         n_act, cap = int(occupied.sum()), self.capacity
         if slots is None:
             free = np.flatnonzero(~occupied)
@@ -200,19 +254,19 @@ class StreamEngine:
                 raise ValueError(
                     f"slots {busy.tolist()} already attached "
                     f"({n_act}/{cap} active); detach or reset them first")
-        self.state = engine_attach(self.state, idx)
+        mask = slot_mask(idx, cap)
+        self._stage(mask, occupied | mask)
         self._m[idx] = self.default_m if m is None else float(m)
         if detectors is not None or vote is not None:
             self.set_detectors(idx, detectors=detectors, vote=vote)
         elif self._ensemble:
-            self._reset_detectors(np.asarray(
-                slot_mask(idx, self.capacity)))
+            self._reset_detectors(mask)
         return idx
 
     def detach(self, slots):
-        self.state = engine_detach(self.state, slots)
+        mask = slot_mask(slots, self.capacity)
+        self._stage(mask, self.active_mask & ~mask)
         # recycled slots revert to the default sensitivity/detectors
-        mask = np.asarray(slot_mask(slots, self.capacity))
         self._m[mask] = self.default_m
         if self._ensemble:
             self._reset_detectors(mask)
@@ -276,7 +330,7 @@ class StreamEngine:
                 "threshold": float(self._det_thr[slot])}
 
     def reset(self, slots=None):
-        self.state = engine_reset(self.state, slots)
+        self._stage(slot_mask(slots, self.capacity), self.active_mask)
 
     def set_m(self, slots, m) -> None:
         """Retune the outlier sensitivity of the selected slots.
@@ -301,15 +355,6 @@ class StreamEngine:
         self._m[idx] = m
 
     # ------------------------------------------------------ processing
-    def _active_mask_host(self) -> np.ndarray:
-        """Host copy of the active mask, cached by the identity of
-        `state.active` (which only attach/detach/reset/resize replace)
-        so per-call metrics never add a device fetch to the hot path."""
-        arr = self.state.active
-        if self._active_cache[0] is not arr:
-            self._active_cache = (arr, np.asarray(arr))
-        return self._active_cache[1]
-
     def _account(self, t_len: int, vc, had_vlens: bool, active) -> None:
         """Update the obs instruments for one `process` call.
 
@@ -324,9 +369,9 @@ class StreamEngine:
         self._c_calls.inc()
         if had_vlens and vc is None:
             return
-        amask = self._active_mask_host()
+        amask = self.active_mask
         if active is not None:
-            amask = amask & np.asarray(slot_mask(active, self.capacity))
+            amask = amask & slot_mask(active, self.capacity)
         if not had_vlens:
             retired = t_key * int(amask.sum())
         elif vc.ndim == 0:
@@ -402,8 +447,8 @@ class StreamEngine:
                 x, st.k, st.mean, st.var, st.aux, vl,
                 jnp.asarray(self.backend.quantize_m(mv)),
                 jnp.asarray(self._det_w), jnp.asarray(self._det_thr))
-            self.state = EngineState(k=k, mean=mean, var=var,
-                                     active=st.active, aux=aux)
+            self._state = EngineState(k=k, mean=mean, var=var,
+                                      active=st.active, aux=aux)
             # det_flags doubles as the backend-native "ecc" stream so
             # the serving stack's fetch plumbing stays structurally
             # unchanged; both keys alias the same array.  "scores" is
@@ -413,13 +458,14 @@ class StreamEngine:
         (k, mean, var), (ecc, outlier) = self._fn(
             x, st.k, st.mean, st.var, vl,
             jnp.asarray(self.backend.quantize_m(mv)))
-        self.state = EngineState(k=k, mean=mean, var=var, active=st.active)
+        self._state = EngineState(k=k, mean=mean, var=var,
+                                  active=st.active)
         return {"ecc": ecc, "outlier": outlier}
 
     # ------------------------------------------------------- introspection
     @property
     def active_slots(self) -> np.ndarray:
-        return np.flatnonzero(np.asarray(self.state.active))
+        return np.flatnonzero(self.active_mask)
 
     @property
     def samples_seen(self) -> np.ndarray:

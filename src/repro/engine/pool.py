@@ -23,7 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.engine.engine import StreamEngine
-from repro.engine.state import EngineState
+from repro.engine.state import EngineState, slot_mask
 from repro.obs import NULL_TRACER, MetricsRegistry, auto_name
 
 __all__ = ["SlotPool", "PoolFull"]
@@ -50,6 +50,9 @@ class SlotPool:
     through to the per-bucket `StreamEngine`s.
     """
 
+    # the spans a traced pool records, one per call of the same name
+    SPANS = ("acquire", "release")
+
     def __init__(self, backend: str = "scan", *,
                  buckets: Tuple[int, ...] = (8, 16, 32, 64),
                  m: float = 3.0, registry=None, tracer=None,
@@ -65,7 +68,7 @@ class SlotPool:
         # observability (repro.obs): the registry is shared with every
         # per-bucket engine (engine series are labelled `<pool>/capN`),
         # so one snapshot covers the whole pool; the tracer records an
-        # `acquire` span per acquisition and a `pool.resize` instant
+        # `acquire` / `release` span per call and a `pool.resize` instant
         self.registry = (MetricsRegistry() if registry is None
                          else registry)
         self.tracer = NULL_TRACER if tracer is None else tracer
@@ -125,8 +128,7 @@ class SlotPool:
 
     @property
     def free_slots(self) -> np.ndarray:
-        act = np.asarray(self.engine.state.active)
-        return np.flatnonzero(~act)
+        return np.flatnonzero(~self.engine.active_mask)
 
     # ------------------------------------------------------- resizing
     def _resize(self, bucket: int) -> None:
@@ -156,7 +158,7 @@ class SlotPool:
 
         dst.state = EngineState(k=pad(st.k, 0), mean=pad(st.mean, 0),
                                 var=pad(st.var, 0),
-                                active=pad(st.active, False),
+                                active=pad(src.active_mask, False),
                                 aux=(None if st.aux is None
                                      else pad2(st.aux)))
         new_m = np.full((bucket,), self.default_m, np.float32)
@@ -203,8 +205,9 @@ class SlotPool:
         the new tenants' detector subset and vote mode under the
         ensemble backend (`StreamEngine.attach`).
 
-        Traced as one `acquire` span (its active-mask fetch waits for
-        the call in flight; `resized` says whether it re-padded).
+        Traced as one `acquire` span (`resized` says whether it
+        re-padded, which fetches the packed state and so waits for the
+        call in flight).
         """
         if not self.tracer.enabled:
             return self._acquire(n, m, detectors, vote)
@@ -217,17 +220,17 @@ class SlotPool:
                 span.set(resized=self._bucket != bucket)
 
     def _acquire(self, n, m, detectors, vote) -> np.ndarray:
-        act = np.asarray(self.engine.state.active)
-        need = int(act.sum()) + n
+        act = self.engine.active_slots
+        need = len(act) + n
         if need > self._bucket:
-            max_idx = int(np.flatnonzero(act).max()) if act.any() else -1
+            max_idx = int(act.max()) if len(act) else -1
             target = self._bucket_holding(need, max_idx)
             if target is None:
                 self._c_full.inc()
                 raise PoolFull(
                     f"pool full: want {n} more slots with "
-                    f"{int(act.sum())}/{self.max_capacity} active at the "
-                    f"top bucket", int(act.sum()), self.max_capacity)
+                    f"{len(act)}/{self.max_capacity} active at the "
+                    f"top bucket", len(act), self.max_capacity)
             self._resize(target)
         idx = self.engine.attach(n=n, m=m, detectors=detectors,
                                  vote=vote)
@@ -236,12 +239,28 @@ class SlotPool:
 
     def release(self, slots) -> None:
         """Detach tenants; shrink to the smallest bucket that still
-        addresses every remaining active slot."""
-        self.engine.detach(slots)
-        act = np.asarray(self.engine.state.active)
-        self._g_occupancy.set(int(act.sum()))
-        max_idx = int(np.flatnonzero(act).max()) if act.any() else -1
-        target = self._bucket_holding(int(act.sum()), max_idx)
+        addresses every remaining active slot.
+
+        Traced as one `release` span, shaped like `acquire`.
+        """
+        mask = slot_mask(slots, self._bucket)
+        if not self.tracer.enabled:
+            self._release(mask)
+            return
+        bucket = self._bucket
+        with self.tracer.span("release", device=True, n=int(mask.sum()),
+                              pool=self.name) as span:
+            try:
+                self._release(mask)
+            finally:
+                span.set(resized=self._bucket != bucket)
+
+    def _release(self, mask: np.ndarray) -> None:
+        self.engine.detach(mask)
+        act = self.engine.active_slots
+        self._g_occupancy.set(len(act))
+        max_idx = int(act.max()) if len(act) else -1
+        target = self._bucket_holding(len(act), max_idx)
         if target is not None and target < self._bucket:
             self._resize(target)
 

@@ -18,6 +18,7 @@ from __future__ import annotations
 
 from typing import NamedTuple, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -68,30 +69,34 @@ def engine_init(capacity: int, dtype=jnp.float32,
                             if aux_rows else None))
 
 
-def slot_mask(slots, capacity: int) -> jnp.ndarray:
+def slot_mask(slots, capacity: int):
     """Normalize a slot selector to a (C,) bool mask.
 
     `slots` may be None (all slots), a bool mask, or integer indices.
-    Concrete indices are bounds-checked — JAX scatter silently drops
-    out-of-range indices, which would turn attach/reset on a bad slot
-    into a successful-looking no-op.  (Traced indices inside jit skip
-    the check.)
+    A concrete selector gives a host (numpy) mask, so slot admin never
+    waits on the device to build one; a traced selector under jit gives
+    a traced mask.  Concrete indices are bounds-checked — JAX scatter
+    silently drops out-of-range indices, which would turn attach/reset
+    on a bad slot into a successful-looking no-op.
     """
+    if isinstance(slots, jax.core.Tracer):
+        if slots.dtype == bool:
+            return slots.reshape((capacity,))
+        return jnp.zeros((capacity,), bool).at[slots].set(True)
     if slots is None:
-        return jnp.ones((capacity,), bool)
-    slots = jnp.asarray(slots)
-    if slots.dtype == bool:
-        return slots.reshape((capacity,))
-    try:
-        idx = np.asarray(slots)
-    except Exception:  # traced under jit: not concretizable
-        idx = None
-    if idx is not None and idx.size and (
-            idx.min() < 0 or idx.max() >= capacity):
+        return np.ones((capacity,), bool)
+    idx = np.asarray(slots)
+    if idx.dtype == bool:
+        return idx.reshape((capacity,))
+    mask = np.zeros((capacity,), bool)
+    if not idx.size:
+        return mask
+    if idx.min() < 0 or idx.max() >= capacity:
         raise IndexError(
             f"slot indices {np.unique(idx).tolist()} out of range for "
             f"capacity {capacity}")
-    return jnp.zeros((capacity,), bool).at[slots].set(True)
+    mask[idx] = True
+    return mask
 
 
 def engine_reset(state: EngineState, slots=None) -> EngineState:
