@@ -31,7 +31,7 @@ from repro.detectors import (DEFAULT_DETECTORS, DEFAULT_WINDOW, DETECTORS,
 from repro.detectors.hst import hst_init, hst_scan
 from repro.detectors.teda_q import teda_q_member_scan
 from repro.detectors.zscore import zscore_init
-from repro.kernels.ensemble_scan import ensemble_pallas_call
+from repro.kernels.ensemble_scan import ensemble_pallas_call, max_block_c
 from repro.kernels.ragged import default_interpret, norm_block_c, pad_layout
 
 __all__ = ["EnsembleState", "ensemble_init", "ensemble_scan",
@@ -176,9 +176,9 @@ def ensemble_scan(x: jnp.ndarray, m=3.0,
     bits, vote, fk, auxf, scores = _padded_ensemble_call(
         x, vlen, k0, mv, thr, sel, jnp.asarray(state.aux, jnp.float32),
         window=window, detectors=detectors, fmt=fmt, block_t=block_t,
-        block_c=norm_block_c(block_c, block_t, c, lane_pad),
-        interpret=interpret,
-        lane_pad=lane_pad)
+        block_c=norm_block_c(block_c, block_t, c, lane_pad,
+                             max_block_c(block_t, detectors, window)),
+        interpret=interpret, lane_pad=lane_pad)
     final = EnsembleState(k=fk, aux=auxf)
     return final, {"det_flags": bits, "vote": vote.astype(bool),
                    "scores": scores}

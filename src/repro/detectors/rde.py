@@ -28,7 +28,7 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-__all__ = ["RdeState", "rde_init", "rde_scan"]
+__all__ = ["RdeState", "rde_init", "rde_scan", "scan_rows"]
 
 
 class RdeState(NamedTuple):
@@ -46,6 +46,25 @@ class RdeState(NamedTuple):
 def rde_init(c: int, dtype=jnp.float32) -> RdeState:
     z = jnp.zeros((c,), dtype)
     return RdeState(k=z, s=z, s2=z)
+
+
+def scan_rows(step, carry, x, valid):
+    """`lax.scan` of a member's `step` over the rows of x (T, C) with
+    their validity mask; returns (carry, outputs).
+
+    A one-row chunk runs as two rows, the second invalid (it advances
+    nothing and its outputs are dropped): XLA inlines a loop of one
+    trip and fuses its body with its neighbours, where the compiler
+    contracts a multiply and an add the loop body keeps apart, so the
+    one-sample decode after a chunked history rounded one float32 ulp
+    off the whole-stream scan.
+    """
+    t_len = x.shape[0]
+    if t_len == 1:
+        x = jnp.concatenate([x, jnp.zeros_like(x)])
+        valid = jnp.concatenate([valid, jnp.zeros_like(valid)])
+    carry, out = jax.lax.scan(step, carry, (x, valid))
+    return carry, jax.tree.map(lambda a: a[:t_len], out)
 
 
 def rde_scan(x: jnp.ndarray, m=3.0, state: Optional[RdeState] = None, *,
@@ -91,6 +110,6 @@ def rde_scan(x: jnp.ndarray, m=3.0, state: Optional[RdeState] = None, *,
         flag = v & (k1 >= 2.0) & ok & (d2 > m2 * varb)
         return (k1, s1, s21), (flag, dens)
 
-    (k, s, s2), (outlier, score) = jax.lax.scan(
-        step, (state.k, state.s, state.s2), (x, valid))
+    (k, s, s2), (outlier, score) = scan_rows(
+        step, (state.k, state.s, state.s2), x, valid)
     return RdeState(k=k, s=s, s2=s2), {"outlier": outlier, "score": score}

@@ -28,8 +28,9 @@ from __future__ import annotations
 
 from typing import NamedTuple, Optional, Tuple
 
-import jax
 import jax.numpy as jnp
+
+from repro.detectors.rde import scan_rows
 
 __all__ = ["ZscoreState", "zscore_init", "zscore_scan"]
 
@@ -102,7 +103,7 @@ def zscore_scan(x: jnp.ndarray, m=3.0,
         flag = v & (k1 >= 2.0) & ok & (d2 > m2 * sig)
         return (k1, ring1), (flag, z2)
 
-    (k, ring), (outlier, score) = jax.lax.scan(
-        step, (state.k, state.ring), (x, valid))
+    (k, ring), (outlier, score) = scan_rows(
+        step, (state.k, state.ring), x, valid)
     return (ZscoreState(k=k, ring=ring),
             {"outlier": outlier, "score": score})

@@ -260,7 +260,7 @@ class StreamEngine:
         if detectors is not None or vote is not None:
             self.set_detectors(idx, detectors=detectors, vote=vote)
         elif self._ensemble:
-            self._reset_detectors(mask)
+            self._reset_detectors(idx)
         return idx
 
     def detach(self, slots):
@@ -271,10 +271,12 @@ class StreamEngine:
         if self._ensemble:
             self._reset_detectors(mask)
 
-    def _reset_detectors(self, mask: np.ndarray) -> None:
-        self._det_w[:, mask] = np.asarray(
+    def _reset_detectors(self, slots: np.ndarray) -> None:
+        """Give `slots` (indices or a boolean mask) the backend's
+        default detectors and vote."""
+        self._det_w[:, slots] = np.asarray(
             self.backend.weights, np.float32)[:, None]
-        self._det_thr[mask] = self.backend.default_threshold
+        self._det_thr[slots] = self.backend.default_threshold
 
     def set_detectors(self, slots=None, *, detectors=None,
                       vote=None) -> None:

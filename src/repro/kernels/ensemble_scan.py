@@ -58,10 +58,12 @@ from repro.detectors.spec import (HST_LEAVES, HST_RANGE, MOMENT_MEMBERS,
                                   i32_to_f32_bits)
 from repro.fixedpoint.qformat import sat_add, sat_mul, sat_sub
 from repro.kernels.qdiv import fast_div_qi, fast_div_qq
+from repro.kernels.ragged import round_up
 from repro.kernels.teda_scan import (_affine_scan_rows, _cumsum_rows,
                                      row_index)
 
-__all__ = ["ensemble_scan_kernel", "ensemble_pallas_call"]
+__all__ = ["ensemble_scan_kernel", "ensemble_pallas_call",
+           "vmem_bytes_per_lane", "max_block_c"]
 
 # (block_t, block_c) VMEM row banks each sequential lane reads and
 # writes one row at a time (Mosaic lowers a dynamic row slice of a
@@ -69,6 +71,44 @@ __all__ = ["ensemble_scan_kernel", "ensemble_pallas_call"]
 # its rk / divider-term / mean / var rows (int32)
 _HST_BANKS = 2
 _TEDA_Q_BANKS = 4
+
+#: Mosaic's scoped-VMEM limit on a TPU v5e ("limit 16.00M" in its
+#: out-of-memory error): a call's blocks, its scratch and the values of
+#: its arithmetic share it
+SCOPED_VMEM_BYTES = 16 << 20
+
+
+def _n_banks(detectors) -> int:
+    return ((_HST_BANKS if "hst" in detectors else 0)
+            + (_TEDA_Q_BANKS if "teda-q" in detectors else 0))
+
+
+def vmem_bytes_per_lane(block_t: int, detectors, window: int) -> int:
+    """VMEM bytes one lane of a (block_t, block_c) call holds in blocks
+    and scratch.  Pallas keeps two copies of every pipelined block: the
+    input x, the vlen / k0 / m / thr rows, the (K, .) selection and the
+    state block in; the bitmask, the int8 vote, fk, the state block and
+    the K score rows out.  One copy of the scratch: the state tile and
+    the sequential lanes' row banks.  Rows count in whole tiles of 8
+    sublanes (32 for int8)."""
+    f32 = 4
+    tt = round_up(block_t, 8)
+    state = round_up(ensemble_spec(detectors, window).rows, 8)
+    k = len(detectors)
+    blocks = ((tt + 4 * 8 + round_up(k, 8) + state      # in
+               + tt + 8 + state + k * tt) * f32         # out
+              + round_up(block_t, 32))                  # the int8 vote
+    return 2 * blocks + (state + _n_banks(detectors) * tt) * f32
+
+
+def max_block_c(block_t: int, detectors, window: int) -> int:
+    """The widest strip, in lanes, whose blocks and scratch take at most
+    half the scoped VMEM; the other half holds the values of the
+    tile's arithmetic, which Mosaic places beside them.  (Compiled for
+    a v5e, K=5: 8,192 lanes at block_t 8 need 16.54 MiB, of which this
+    count is 89%; 512 lanes at block_t 256 need 23.51 MiB, 46%.)"""
+    return SCOPED_VMEM_BYTES // 2 // vmem_bytes_per_lane(
+        block_t, detectors, window)
 
 
 def _hst_lane(state, spec, x_ref, row_valid, m, banks, *, window: int):

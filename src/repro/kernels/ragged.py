@@ -16,12 +16,11 @@ import jax.numpy as jnp
 __all__ = ["default_interpret", "round_up", "norm_block_c", "vlen_vec",
            "mask_ragged_rows", "pad_layout", "TILE_ELEMS"]
 
-# Default cap on a kernel tile, block_t * block_c elements.  The widest
-# tiles that compile within a TPU v5e's scoped VMEM at block_t=256 are
-# 256 x 256 for the K=5 ensemble (the tightest kernel: score streams
-# plus the hst / teda-q row banks), 256 x 512 for the Q verdict kernel
-# and the K=3 ensemble, and 256 x 1024 for the float verdict kernel
-# (tests/test_tpu_compile.py compiles the main path at this default).
+# The float and Q TEDA kernels' default tile, block_t * block_c
+# elements: the tiling `linerate-q` was measured at (one strip up to
+# 8,192 lanes at the scheduler's block_t of 8).  The fused ensemble
+# sizes its strips from its own VMEM count instead
+# (`kernels/ensemble_scan.py` `max_block_c`).
 TILE_ELEMS = 256 * 256
 
 
@@ -44,16 +43,19 @@ def round_up(v: int, mult: int) -> int:
     return -(-v // mult) * mult
 
 
-def norm_block_c(block_c, block_t: int, c: int, lane_pad: int) -> int:
+def norm_block_c(block_c, block_t: int, c: int, lane_pad: int,
+                 max_lanes=None) -> int:
     """Normalize the channel-block width to a static int (0 = one strip).
 
     `None` picks the default: one strip when the lane-padded width fits
-    the `TILE_ELEMS` tile budget at this `block_t`, else the widest
-    strip (a multiple of 128 dividing the padded width) that does.
+    within `max_lanes` lanes (the kernel's widest strip at this
+    `block_t`; None: the TEDA kernels' `TILE_ELEMS // block_t`), else
+    the widest strip (a multiple of 128 dividing the padded width) that
+    does.
     """
     if block_c is None:
         cp = round_up(c, lane_pad)
-        cap = TILE_ELEMS // block_t
+        cap = TILE_ELEMS // block_t if max_lanes is None else max_lanes
         if cp <= cap:
             return 0
         bc = max(128, cap // 128 * 128)

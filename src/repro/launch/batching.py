@@ -174,11 +174,12 @@ class _Run:
 
     __slots__ = ("req", "slot", "shard", "pending", "cursor", "phase",
                  "stats", "ecc_parts", "outlier_parts", "hist_len",
-                 "consumed", "inflight")
+                 "consumed", "inflight", "seq")
 
     def __init__(self, req: Request, slot: int, stats: RequestStats,
-                 shard: int = 0):
+                 shard: int = 0, seq: int = 0):
         self.req = req
+        self.seq = seq  # admission order
         self.slot = slot
         self.shard = shard
         self.pending = np.asarray(req.history, np.float32).reshape(-1)
@@ -236,6 +237,10 @@ class _InFlight:
         self.t0 = t0
         self.sync_wall = sync_wall  # honest wall when measured sync
         self.shard = shard          # which shard's engine ran the call
+
+
+def _by_admission(run: _Run) -> int:
+    return run.seq
 
 
 def _host_ready(out) -> bool:
@@ -369,8 +374,14 @@ class BatchingScheduler:
         self._weights: Dict[str, float] = dict(class_weights or {})
         self._ctor_classes = frozenset(self._weights)
         self._queues: "OrderedDict[str, deque]" = OrderedDict()
+        self._queued: Dict[str, Request] = {}   # the queues' requests
         self._deficit: Dict[str, float] = {}
         self.runs: Dict[str, _Run] = {}     # admitted, not yet done
+        # the admitted runs with samples pending, and those closed: a
+        # tick visits these, not every run (`_by_admission` orders them)
+        self._ready: Dict[str, _Run] = {}
+        self._closing: Dict[str, _Run] = {}
+        self._admitted = 0
         self._finished: Dict[str, _Run] = {}
         self._evicted: deque = deque(maxlen=max(4096, self.keep_finished))
         # rid -> live entries in the ring (a rid can re-enter after a
@@ -529,6 +540,7 @@ class BatchingScheduler:
             rid=req.rid, submitted_tick=self.tick_no,
             priority=req.priority)
         self._queues.setdefault(req.priority, deque()).append(req)
+        self._queued[req.rid] = req
         self._c_submitted.inc()
         self._cls(req.priority)["queued"].inc()
         return True
@@ -540,15 +552,17 @@ class BatchingScheduler:
             if run.req.closed:
                 raise ValueError(f"request {rid!r} is closed")
             run.push(samples)
+            if run.avail:
+                self._ready[rid] = run
             return
-        for req in self.queue:  # not yet admitted: samples are backlog
-            if req.rid == rid:
-                if req.closed:
-                    raise ValueError(f"request {rid!r} is closed")
-                req.history = np.concatenate(
-                    [np.asarray(req.history, np.float32).reshape(-1),
-                     np.asarray(samples, np.float32).reshape(-1)])
-                return
+        req = self._queued.get(rid)
+        if req is not None:  # not yet admitted: samples are backlog
+            if req.closed:
+                raise ValueError(f"request {rid!r} is closed")
+            req.history = np.concatenate(
+                [np.asarray(req.history, np.float32).reshape(-1),
+                 np.asarray(samples, np.float32).reshape(-1)])
+            return
         raise KeyError(f"unknown or finished request {rid!r}")
 
     def close(self, rid: str) -> None:
@@ -556,11 +570,12 @@ class BatchingScheduler:
         run = self.runs.get(rid)
         if run is not None:
             run.req.closed = True
+            self._closing[rid] = run
             return
-        for req in self.queue:
-            if req.rid == rid:
-                req.closed = True
-                return
+        req = self._queued.get(rid)
+        if req is not None:
+            req.closed = True
+            return
         raise KeyError(f"unknown or finished request {rid!r}")
 
     # --------------------------------------------------------- the tick
@@ -629,14 +644,20 @@ class BatchingScheduler:
                         blocked.add(cls)  # this head's shard is full
                         break
                     q.popleft()
+                    del self._queued[req.rid]
                     self._deficit[cls] -= 1.0
                     st = self.stats_by_rid[req.rid]
                     st.admitted_tick = self.tick_no
                     st.slot = slot
                     if self._sharded:
                         st.shard = shard
-                    self.runs[req.rid] = _Run(req, slot, st,
-                                              shard=shard)
+                    run = self.runs[req.rid] = _Run(
+                        req, slot, st, shard=shard, seq=self._admitted)
+                    self._admitted += 1
+                    if run.avail:
+                        self._ready[req.rid] = run
+                    if req.closed:
+                        self._closing[req.rid] = run
                     events["admitted"].append(req.rid)
                     ch = self._cls(req.priority)
                     ch["queued"].dec()
@@ -692,6 +713,8 @@ class BatchingScheduler:
             for run in members:
                 n = min(run.avail, t_len)
                 x[:n, run.slot] = run.take(n)
+                if not run.avail:
+                    del self._ready[run.req.rid]
                 vlens[run.slot] = n
                 run.inflight += 1
                 mem.append((run, run.slot, n))
@@ -776,36 +799,37 @@ class BatchingScheduler:
         stream = self.events.active
         flagged = (events["flagged"] if events is not None
                    else self._deferred_flagged)
-        for run, slot, n in inf.members:
+        members = inf.members
+        slots = np.fromiter((s for _, s, _ in members), np.intp,
+                            len(members))
+        ns = np.fromiter((n for _, _, n in members), np.intp, len(members))
+        # every member's rows gathered once, (slots, t) up to the longest
+        # it retired: row i holds members[i]'s column, valid over its
+        # first n samples (the engine zeroes each slot's outputs past
+        # its valid rows)
+        t_max = int(ns.max())
+        cols = np.ascontiguousarray(outlier[:t_max, slots].T)
+        n_flags = cols.sum(axis=1).tolist()
+        per_member = (self._members(ecc, scores, slots, t_max)
+                      if self._ensemble else None)
+        for i, (run, slot, n) in enumerate(members):
             st = run.stats
             st.samples += n
             if len(st.chunk_latency_s) < self.latency_log_len:
                 st.chunk_latency_s.append((wall, n))
-            col = outlier[:n, slot]
-            nf = int(col.sum())
+            col = cols[i, :n]
+            nf = n_flags[i]
             st.flags += nf
             if nf:
                 flagged.append(run.req.rid)
                 self._c_flags.inc(nf)
-            det_counts = None
-            det_sums = None
-            if self._ensemble:
-                # bit d of the "ecc" bitmask column is detectors[d]
-                col_bits = ecc[:n, slot].astype(np.int64)
-                det_counts = {}
-                for d, det in enumerate(self._det_names):
-                    c = int(((col_bits >> d) & 1).sum())
-                    if c:
-                        det_counts[det] = c
-                        self._det_counter(det).inc(c)
-                        st.det_flags[det] = st.det_flags.get(det, 0) + c
-                if scores is not None and n:
-                    # row d of the score block is detectors[d]'s float
-                    # score stream over this slot's retired prefix
-                    det_sums = {}
-                    for d, det in enumerate(self._det_names):
-                        s = float(scores[d, :n, slot].sum())
-                        det_sums[det] = s
+            det_counts = det_sums = col_bits = None
+            if per_member is not None:
+                det_counts, det_sums, col_bits = per_member[i]
+                for det, c in det_counts.items():
+                    st.det_flags[det] = st.det_flags.get(det, 0) + c
+                if det_sums is not None:
+                    for det, s in det_sums.items():
                         st.det_scores[det] = (
                             st.det_scores.get(det, 0.0) + s)
             if n > 1:
@@ -813,8 +837,10 @@ class BatchingScheduler:
             else:
                 st.decode_steps += 1    # the 1-sample decode trickle
             if self.collect:
-                run.ecc_parts.append(ecc[:n, slot].copy())
-                run.outlier_parts.append(col.copy())
+                ecc_col = (col_bits[:n] if col_bits is not None
+                           else ecc[:n, slot].copy())
+                run.ecc_parts.append(ecc_col)
+                run.outlier_parts.append(col)
             if stream:
                 data = {"slot": slot, "n": n, "flags": nf,
                         "dispatch_tick": inf.tick,
@@ -822,7 +848,7 @@ class BatchingScheduler:
                 if inf.shard is not None:
                     data["shard"] = inf.shard
                 if self.collect:
-                    data["ecc"] = ecc[:n, slot].copy()
+                    data["ecc"] = ecc_col.copy()
                 if det_counts is not None:
                     data["det_flags"] = det_counts
                     data["detectors"] = self._det_names
@@ -832,6 +858,50 @@ class BatchingScheduler:
                                     run.req.rid, **data)
             run.inflight -= 1
         self._g_inflight.set(len(self._inflight))
+
+    def _members(self, ecc: np.ndarray, scores: Optional[np.ndarray],
+                 slots: np.ndarray, t_max: int) -> list:
+        """Per-member accounting of one call on the ensemble backend,
+        for every member slot at once: per slot its per-detector flag
+        counts (nonzero only), score sums (None without scores) and
+        bitmask row (its first n samples valid; kept by `collect`).
+        Counts the per-detector flag counters.  The engine zeroes the
+        bitmask and the scores past each slot's valid rows, so columns
+        are reduced whole, up to the call's longest `t_max`: gathered
+        first when the call holds few of the pool's slots, else reduced
+        first and then picked.  Traced as a `members` span (`k`
+        detectors, `slots`)."""
+        tracer = self.tracer
+        names = self._det_names
+        with (tracer.span("members", device=True, tick=self.tick_no,
+                          k=len(names), slots=len(slots))
+              if tracer.enabled else NULL_SPAN):
+            few = 4 * len(slots) < ecc.shape[1]
+            pick = slice(None) if few else slots
+            # bit d of the "ecc" bitmask is detectors[d]
+            bits = ecc[:t_max, slots] if few else ecc[:t_max]
+            counts = np.stack([np.count_nonzero(bits & (1 << d), axis=0)
+                               for d in range(len(names))])[:, pick]
+            for d, det in enumerate(names):
+                total = int(counts[d].sum())
+                if total:
+                    self._det_counter(det).inc(total)
+            counts = counts.T.tolist()
+            if scores is not None:
+                # row d of the score block is detectors[d]'s float
+                # score stream
+                sc = scores[:, :t_max, slots] if few else scores[:, :t_max]
+                sums = sc.sum(axis=1)[:, pick].T.tolist()
+            rows = (np.ascontiguousarray((bits if few else bits[:, slots]).T)
+                    if self.collect else None)
+            out = []
+            for i, row in enumerate(counts):
+                det_counts = {det: c for det, c in zip(names, row) if c}
+                det_sums = (dict(zip(names, sums[i]))
+                            if scores is not None else None)
+                out.append((det_counts, det_sums,
+                            rows[i] if rows is not None else None))
+            return out
 
     def _flush(self, events: Optional[dict] = None) -> None:
         """Retire every in-flight call (the consume-side sync)."""
@@ -868,7 +938,7 @@ class BatchingScheduler:
                 and self.tick_no % self.rebalance_every == 0):
             self._rebalance()
         self._admit(events)
-        ready = [r for r in self.runs.values() if r.avail > 0]
+        ready = sorted(self._ready.values(), key=_by_admission)
         deep = self.pipeline_depth > 1 and not self.measure_latency
         if deep and ready:
             # fence: a slot in a still-in-flight call cannot join a new
@@ -912,8 +982,9 @@ class BatchingScheduler:
                     or self._inflight[0].tick < self.tick_no):
                 self._retire(self._inflight.popleft(), events)
 
-        done = [rid for rid, r in self.runs.items()
-                if r.req.closed and r.avail == 0]
+        done = [r.req.rid for r in sorted(self._closing.values(),
+                                          key=_by_admission)
+                if r.avail == 0]
         if any(self.runs[rid].inflight for rid in done):
             # completion consumes results: sync the tail call now so
             # done_tick/telemetry are final the tick the stream drains
@@ -930,6 +1001,7 @@ class BatchingScheduler:
         evicting the oldest finished records past `keep_finished`."""
         for rid in done:
             run = self.runs.pop(rid)
+            del self._closing[rid]
             run.phase = DONE
             st = run.stats
             st.done_tick = self.tick_no

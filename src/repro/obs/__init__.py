@@ -13,8 +13,10 @@ Four parts, all host-side and zero-overhead when unused:
     is the free disabled default.  `BatchingScheduler.step()` records
     one `tick` span holding a span per phase: `admission` (with
     `acquire` per `SlotPool.acquire`), `assemble`, `dispatch`,
-    `retire` (the output fetch), `account`, `complete` (with `release`
-    per `SlotPool.release`), and `flush` when it syncs early; instants
+    `retire` (the output fetch), `account` (with `members`, the
+    ensemble's per-member accounting, on the ensemble backend),
+    `complete` (with `release` per `SlotPool.release`), and `flush`
+    when it syncs early; instants
     mark `admit`, `pool.resize` and `shard.migrate`.  Timestamps are
     relative to `TickTracer.origin`, a `time.perf_counter()` value.
   * `compiles` — `compile_watch()`, the process's one listener on

@@ -412,6 +412,53 @@ def test_tick_spans_nest_every_phase(shards):
     assert sum(e["args"]["completed"] for e in complete) == len(specs)
 
 
+K5 = ("teda", "rde", "zscore", "hst", "teda-q")
+
+
+@pytest.mark.parametrize("collect", [True, False])
+def test_members_span_once_per_ensemble_call(collect):
+    """On the five-member ensemble, each retired call's `account` span
+    holds exactly one `members` span, with `k` = 5 and the call's slot
+    count; the per-member counts it makes are the ones published."""
+    specs = _specs(5, seed=17)
+    tr = TickTracer(capacity=1 << 14)
+    sched = BatchingScheduler("ensemble", fmt=FMT, detectors=K5,
+                              buckets=(2, 4), chunk_t=4, collect=collect,
+                              tracer=tr)
+    sub = sched.subscribe(maxlen=1 << 14)
+    _run_workload(sched, specs)
+    evs = [e for e in tr.events() if e["ph"] == "X"]
+    account = [e for e in evs if e["name"] == "account"]
+    members = [e for e in evs if e["name"] == "members"]
+    assert len(members) == len(account) == int(sched._c_calls.value) > 0
+    eps = 1e-3
+    for acc, mem in zip(account, members):
+        assert acc["ts"] - eps <= mem["ts"]
+        assert mem["ts"] + mem["dur"] <= acc["ts"] + acc["dur"] + eps
+        assert mem["args"]["k"] == 5
+        assert mem["args"]["slots"] == acc["args"]["slots"]
+        assert mem["args"]["tick"] == acc["args"]["tick"]
+    chunks = [e for e in sub.poll() if e.kind == "chunk_retired"]
+    totals = {}
+    for e in chunks:
+        assert set(e.data["det_scores"]) == set(K5)
+        assert all(c > 0 for c in e.data["det_flags"].values())
+        for det, c in e.data["det_flags"].items():
+            totals[det] = totals.get(det, 0) + c
+        assert ("ecc" in e.data) == collect
+    assert totals and totals == sched.stats()["detector_flags"]
+
+
+@pytest.mark.parametrize("backend", ["pallas-q", "scan"])
+def test_no_members_span_off_the_ensemble(backend):
+    tr = TickTracer(capacity=1 << 14)
+    sched = BatchingScheduler(backend, fmt=FMT, buckets=(2, 4),
+                              chunk_t=4, tracer=tr)
+    _run_workload(sched, _specs(4, seed=19))
+    names = {e["name"] for e in tr.events()}
+    assert "account" in names and "members" not in names
+
+
 @pytest.mark.parametrize("backend,collect", [("scan", True),
                                              ("pallas-q", False)])
 def test_transfer_bytes_match_what_moves(backend, collect):
