@@ -81,6 +81,7 @@ from __future__ import annotations
 
 import time
 from collections import OrderedDict, deque
+from collections.abc import Mapping
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
@@ -137,6 +138,15 @@ class RequestStats:
     percentiles weight each observation by the samples that request
     actually retired in the call, instead of attributing the whole
     wall to a slot that retired one sample.
+
+    While a request runs, its counters (`samples`, `flags`,
+    `prefill_chunks`, `decode_steps`, `det_flags`, `det_scores`) live
+    in the scheduler's per-request rows, which each retired call
+    updates as columns.  They are copied here (a fold, counted by
+    `sched_stats_folds_total`) when the request completes, before its
+    `done` event, and whenever it is read through
+    `BatchingScheduler.telemetry()` or `stats_by_rid`.  A `RequestStats`
+    object kept from an earlier read holds the values of that read.
     """
 
     rid: str
@@ -174,14 +184,15 @@ class _Run:
 
     __slots__ = ("req", "slot", "shard", "pending", "cursor", "phase",
                  "stats", "ecc_parts", "outlier_parts", "hist_len",
-                 "consumed", "inflight", "seq")
+                 "consumed", "inflight", "seq", "row")
 
     def __init__(self, req: Request, slot: int, stats: RequestStats,
-                 shard: int = 0, seq: int = 0):
+                 shard: int = 0, seq: int = 0, row: int = 0):
         self.req = req
         self.seq = seq  # admission order
         self.slot = slot
         self.shard = shard
+        self.row = row  # its counters in `_Rows`: kept across migrations
         self.pending = np.asarray(req.history, np.float32).reshape(-1)
         self.cursor = 0
         # the replayed prefix: everything backlogged at admission is
@@ -225,18 +236,88 @@ class _InFlight:
     """One dispatched-but-unfetched fused call (device arrays are JAX
     async futures; fetching them is the sync point)."""
 
-    __slots__ = ("out", "members", "t_len", "tick", "t0", "sync_wall",
-                 "shard")
+    __slots__ = ("out", "runs", "slots", "ns", "t_len", "tick", "t0",
+                 "sync_wall", "shard")
 
-    def __init__(self, out, members, t_len, tick, t0, sync_wall,
+    def __init__(self, out, runs, slots, ns, t_len, tick, t0, sync_wall,
                  shard=None):
         self.out = out              # {"ecc", "outlier"} device arrays
-        self.members = members      # [(run, col, n)] at dispatch time
+        # the member runs, and as arrays their slots and sample counts
+        # at dispatch time: columns, not a tuple per member, so a call
+        # leaves no per-member object for the garbage collector
+        self.runs = runs
+        self.slots = slots
+        self.ns = ns
         self.t_len = t_len
         self.tick = tick
         self.t0 = t0
         self.sync_wall = sync_wall  # honest wall when measured sync
         self.shard = shard          # which shard's engine ran the call
+
+
+class _Rows:
+    """The running requests' counters, one row each: a run takes a row
+    at admission and gives it back at completion, and a retired call
+    adds to all its members' rows with one fancy-indexed add per field.
+    `det_flags` / `det_scores` hold one column per ensemble member
+    (none off the ensemble); `scored` marks rows that have had score
+    sums added.  Score sums stay float64, as the dicts they replace."""
+
+    FIELDS = ("samples", "flags", "prefill_chunks", "decode_steps",
+              "det_flags", "det_scores", "scored")
+
+    def __init__(self, k: int, capacity: int = 64):
+        self.size = 0          # rows ever handed out
+        self._free: List[int] = []
+        self.samples = np.zeros((capacity,), np.int64)
+        self.flags = np.zeros((capacity,), np.int64)
+        self.prefill_chunks = np.zeros((capacity,), np.int64)
+        self.decode_steps = np.zeros((capacity,), np.int64)
+        self.det_flags = np.zeros((capacity, k), np.int64)
+        self.det_scores = np.zeros((capacity, k), np.float64)
+        self.scored = np.zeros((capacity,), bool)
+
+    def take(self) -> int:
+        if self._free:
+            row = self._free.pop()
+            for f in self.FIELDS:
+                getattr(self, f)[row] = 0
+            return row
+        cap = self.samples.shape[0]
+        if self.size == cap:
+            for f in self.FIELDS:
+                a = getattr(self, f)
+                grown = np.zeros((2 * cap,) + a.shape[1:], a.dtype)
+                grown[:cap] = a
+                setattr(self, f, grown)
+        self.size += 1
+        return self.size - 1
+
+    def give(self, row: int) -> None:
+        self._free.append(row)
+
+
+class _StatsView(Mapping):
+    """`BatchingScheduler.stats_by_rid`: every request's `RequestStats`
+    by rid, a running request's folded from its row when read."""
+
+    def __init__(self, stats: Dict[str, RequestStats], fold):
+        self._stats = stats
+        self._fold = fold
+
+    def __getitem__(self, rid: str) -> RequestStats:
+        st = self._stats[rid]
+        self._fold(rid)
+        return st
+
+    def __contains__(self, rid) -> bool:
+        return rid in self._stats
+
+    def __iter__(self):
+        return iter(self._stats)
+
+    def __len__(self) -> int:
+        return len(self._stats)
 
 
 def _by_admission(run: _Run) -> int:
@@ -387,7 +468,11 @@ class BatchingScheduler:
         # rid -> live entries in the ring (a rid can re-enter after a
         # resubmit cycle, so membership is refcounted, not a set)
         self._evicted_counts: Dict[str, int] = {}
-        self.stats_by_rid: Dict[str, RequestStats] = {}
+        # every request's RequestStats by rid; a running request's
+        # counters live in its row of `_rows` until folded (`_fold`)
+        self._stats: Dict[str, RequestStats] = {}
+        self._rows = _Rows(len(self._det_names))
+        self.stats_by_rid = _StatsView(self._stats, self._fold_rid)
         self.call_log: deque = deque(maxlen=int(call_log_len))
         self._inflight: deque = deque()   # dispatched, not host-fetched
         self._deferred_flagged: List[str] = []
@@ -433,6 +518,11 @@ class BatchingScheduler:
             "sched_d2h_bytes_total",
             "bytes of call outputs fetched from the device",
             ("sched",)).labels(**lbl)
+        self._c_folds = reg.counter(
+            "sched_stats_folds_total",
+            "copies of a running request's counter row into its "
+            "RequestStats (completion, telemetry() and stats_by_rid "
+            "reads)", ("sched",)).labels(**lbl)
         self._g_inflight = reg.gauge(
             "sched_inflight_calls",
             "dispatched fused calls not yet host-fetched",
@@ -525,7 +615,7 @@ class BatchingScheduler:
     def submit(self, req: Request) -> bool:
         """Queue a request for admission; False = queue full (caller
         backpressure — retry later or shed load)."""
-        if req.rid in self.stats_by_rid:
+        if req.rid in self._stats:
             raise ValueError(f"duplicate request id {req.rid!r}")
         if self.queued_total >= self.queue_limit:
             self._c_rejected.inc()
@@ -536,7 +626,7 @@ class BatchingScheduler:
             # unknown classes admit at unit weight (documented) rather
             # than rejecting: the weights dict is a tuning knob
             self._weights[req.priority] = 1.0
-        self.stats_by_rid[req.rid] = RequestStats(
+        self._stats[req.rid] = RequestStats(
             rid=req.rid, submitted_tick=self.tick_no,
             priority=req.priority)
         self._queues.setdefault(req.priority, deque()).append(req)
@@ -646,13 +736,14 @@ class BatchingScheduler:
                     q.popleft()
                     del self._queued[req.rid]
                     self._deficit[cls] -= 1.0
-                    st = self.stats_by_rid[req.rid]
+                    st = self._stats[req.rid]
                     st.admitted_tick = self.tick_no
                     st.slot = slot
                     if self._sharded:
                         st.shard = shard
                     run = self.runs[req.rid] = _Run(
-                        req, slot, st, shard=shard, seq=self._admitted)
+                        req, slot, st, shard=shard, seq=self._admitted,
+                        row=self._rows.take())
                     self._admitted += 1
                     if run.avail:
                         self._ready[req.rid] = run
@@ -709,7 +800,6 @@ class BatchingScheduler:
             span.set(t=t_len)
             x = np.zeros((t_len, cap), np.float32)
             vlens = np.zeros((cap,), np.int32)
-            mem = []
             for run in members:
                 n = min(run.avail, t_len)
                 x[:n, run.slot] = run.take(n)
@@ -717,14 +807,15 @@ class BatchingScheduler:
                     del self._ready[run.req.rid]
                 vlens[run.slot] = n
                 run.inflight += 1
-                mem.append((run, run.slot, n))
+            slots = np.fromiter((r.slot for r in members), np.intp,
+                                len(members))
+            ns = vlens[slots].astype(np.intp)
         self._c_calls.inc()
         h2d = x.nbytes + vlens.nbytes
         self._c_h2d.inc(h2d)
         with (tracer.span("dispatch", device=True, tick=self.tick_no,
-                          t=t_len, slots=len(mem), shard=shard,
-                          samples=int(sum(n for _, _, n in mem)),
-                          h2d_bytes=h2d)
+                          t=t_len, slots=len(members), shard=shard,
+                          samples=int(ns.sum()), h2d_bytes=h2d)
               if tracer.enabled else NULL_SPAN):
             t0 = time.perf_counter()
             if self._sharded:
@@ -736,7 +827,7 @@ class BatchingScheduler:
                 jax.block_until_ready(out["ecc"])
                 sync_wall = time.perf_counter() - t0
         self._inflight.append(_InFlight(
-            out, mem, t_len, self.tick_no, t0, sync_wall,
+            out, members, slots, ns, t_len, self.tick_no, t0, sync_wall,
             shard=shard if self._sharded else None))
         self._g_inflight.set(len(self._inflight))
 
@@ -768,27 +859,33 @@ class BatchingScheduler:
         tracer = self.tracer
         with (tracer.span("retire", device=True, tick=self.tick_no,
                           dispatch_tick=inf.tick, t=inf.t_len,
-                          slots=len(inf.members), d2h_bytes=d2h)
+                          slots=len(inf.runs), d2h_bytes=d2h)
               if tracer.enabled else NULL_SPAN):
             outlier = np.asarray(out["outlier"])
             ecc = np.asarray(out["ecc"]) if want_ecc else None
             scores = np.asarray(out["scores"]) if want_scores else None
         with (tracer.span("account", device=True, tick=self.tick_no,
-                          slots=len(inf.members))
+                          slots=len(inf.runs))
               if tracer.enabled else NULL_SPAN):
             self._account(inf, outlier, ecc, scores, events)
 
     def _account(self, inf: _InFlight, outlier: np.ndarray,
                  ecc: Optional[np.ndarray], scores: Optional[np.ndarray],
                  events: Optional[dict]) -> None:
-        """Account one fetched call: the call log and wall histogram,
-        then per member its telemetry, flag counters and
-        `chunk_retired` event."""
+        """Account one fetched call as columns: the call log and wall
+        histogram, every member's counter row by one array update per
+        field, the flag counters, then per member only its latency
+        pair, its `collect` parts and its `chunk_retired` event.  The
+        gathered outlier and bitmask rows are read-only, so `collect`
+        and the events keep views of them, not copies."""
         wall = (inf.sync_wall if inf.sync_wall is not None
                 else time.perf_counter() - inf.t0)
-        retired = int(sum(n for _, _, n in inf.members))
+        runs, slots, ns = inf.runs, inf.slots, inf.ns
+        m = len(runs)
+        rows = np.fromiter((r.row for r in runs), np.intp, m)
+        retired = int(ns.sum())
         self.call_log.append({
-            "kind": "fused", "t": inf.t_len, "slots": len(inf.members),
+            "kind": "fused", "t": inf.t_len, "slots": m,
             "retired": retired,
             "wall_s": wall, "sync": inf.sync_wall is not None})
         # running latency instrument: each call weighted by the samples
@@ -796,81 +893,106 @@ class BatchingScheduler:
         # per-call re-sort of the whole log is gone)
         self._h_wall.observe(wall * 1e3, weight=max(retired, 1))
         self._c_samples.inc(retired)
-        stream = self.events.active
-        flagged = (events["flagged"] if events is not None
-                   else self._deferred_flagged)
-        members = inf.members
-        slots = np.fromiter((s for _, s, _ in members), np.intp,
-                            len(members))
-        ns = np.fromiter((n for _, _, n in members), np.intp, len(members))
         # every member's rows gathered once, (slots, t) up to the longest
-        # it retired: row i holds members[i]'s column, valid over its
+        # it retired: row i holds runs[i]'s column, valid over its
         # first n samples (the engine zeroes each slot's outputs past
         # its valid rows)
         t_max = int(ns.max())
         cols = np.ascontiguousarray(outlier[:t_max, slots].T)
-        n_flags = cols.sum(axis=1).tolist()
-        per_member = (self._members(ecc, scores, slots, t_max)
-                      if self._ensemble else None)
-        for i, (run, slot, n) in enumerate(members):
-            st = run.stats
-            st.samples += n
-            if len(st.chunk_latency_s) < self.latency_log_len:
-                st.chunk_latency_s.append((wall, n))
-            col = cols[i, :n]
-            nf = n_flags[i]
-            st.flags += nf
-            if nf:
-                flagged.append(run.req.rid)
-                self._c_flags.inc(nf)
-            det_counts = det_sums = col_bits = None
-            if per_member is not None:
-                det_counts, det_sums, col_bits = per_member[i]
-                for det, c in det_counts.items():
-                    st.det_flags[det] = st.det_flags.get(det, 0) + c
-                if det_sums is not None:
-                    for det, s in det_sums.items():
-                        st.det_scores[det] = (
-                            st.det_scores.get(det, 0.0) + s)
-            if n > 1:
-                st.prefill_chunks += 1  # a multi-sample (chunked) ride
-            else:
-                st.decode_steps += 1    # the 1-sample decode trickle
+        cols.flags.writeable = False
+        n_flags = cols.sum(axis=1)
+        counts = sums = bits = None
+        if self._ensemble:
+            counts, sums, bits = self._members(ecc, scores, slots, t_max)
+        elif self.collect:
+            bits = np.ascontiguousarray(ecc[:t_max, slots].T)
+            bits.flags.writeable = False
+        acc = self._rows
+        acc.samples[rows] += ns
+        acc.flags[rows] += n_flags
+        multi = ns > 1      # a multi-sample (chunked) ride
+        acc.prefill_chunks[rows] += multi
+        acc.decode_steps[rows] += ~multi   # the 1-sample decode trickle
+        if counts is not None:
+            acc.det_flags[rows] += counts
+        if sums is not None:
+            # float32 sums added to float64 rows: bit for bit what
+            # per-member float additions give
+            acc.det_scores[rows] += sums
+            acc.scored[rows] = True
+        total = int(n_flags.sum())
+        if total:
+            self._c_flags.inc(total)
+            flagged = (events["flagged"] if events is not None
+                       else self._deferred_flagged)
+            flagged.extend([runs[i].req.rid
+                            for i in np.flatnonzero(n_flags).tolist()])
+        # each member's rows as views, made once for `collect` and the
+        # events both
+        stream = self.events.active
+        outs, eccs = [], []
+        cap = self.latency_log_len
+        for i, (run, n) in enumerate(zip(runs, ns.tolist())):
+            lat = run.stats.chunk_latency_s
+            if len(lat) < cap:
+                lat.append((wall, n))
+            if self.collect or stream:
+                outs.append(cols[i, :n])
+                if bits is not None:
+                    eccs.append(bits[i, :n])
             if self.collect:
-                ecc_col = (col_bits[:n] if col_bits is not None
-                           else ecc[:n, slot].copy())
-                run.ecc_parts.append(ecc_col)
-                run.outlier_parts.append(col)
-            if stream:
-                data = {"slot": slot, "n": n, "flags": nf,
-                        "dispatch_tick": inf.tick,
-                        "outlier": col.copy()}
-                if inf.shard is not None:
-                    data["shard"] = inf.shard
-                if self.collect:
-                    data["ecc"] = ecc_col.copy()
-                if det_counts is not None:
-                    data["det_flags"] = det_counts
-                    data["detectors"] = self._det_names
-                if det_sums is not None:
-                    data["det_scores"] = det_sums
-                self.events.publish("chunk_retired", self.tick_no,
-                                    run.req.rid, **data)
+                run.outlier_parts.append(outs[i])
+                run.ecc_parts.append(eccs[i])
             run.inflight -= 1
+        if stream:
+            self.events.publish_many(
+                "chunk_retired", self.tick_no,
+                zip([r.req.rid for r in runs],
+                    self._retired_events(inf, outs, eccs, n_flags, counts,
+                                         sums)))
         self._g_inflight.set(len(self._inflight))
 
+    def _retired_events(self, inf: _InFlight, outs, eccs, n_flags,
+                        counts, sums):
+        """The data of each member's `chunk_retired` event: its outlier
+        and bitmask row views, the rest from `.tolist()` of the call's
+        arrays.  The (members, K) arrays are listed flat and sliced per
+        member: one list a call, where a list per member would outlive
+        the loop and add to what the garbage collector scans."""
+        names = self._det_names
+        k = len(names)
+        n_flags = n_flags.tolist()
+        counts = counts.ravel().tolist() if counts is not None else None
+        sums = sums.ravel().tolist() if sums is not None else None
+        for i, (slot, n) in enumerate(zip(inf.slots.tolist(),
+                                          inf.ns.tolist())):
+            data = {"slot": slot, "n": n, "flags": n_flags[i],
+                    "dispatch_tick": inf.tick, "outlier": outs[i]}
+            if inf.shard is not None:
+                data["shard"] = inf.shard
+            if self.collect:
+                data["ecc"] = eccs[i]
+            j = i * k
+            if counts is not None:
+                data["det_flags"] = {d: c for d, c in
+                                     zip(names, counts[j:j + k]) if c}
+                data["detectors"] = names
+            if sums is not None:
+                data["det_scores"] = dict(zip(names, sums[j:j + k]))
+            yield data
+
     def _members(self, ecc: np.ndarray, scores: Optional[np.ndarray],
-                 slots: np.ndarray, t_max: int) -> list:
-        """Per-member accounting of one call on the ensemble backend,
-        for every member slot at once: per slot its per-detector flag
-        counts (nonzero only), score sums (None without scores) and
-        bitmask row (its first n samples valid; kept by `collect`).
-        Counts the per-detector flag counters.  The engine zeroes the
-        bitmask and the scores past each slot's valid rows, so columns
-        are reduced whole, up to the call's longest `t_max`: gathered
-        first when the call holds few of the pool's slots, else reduced
-        first and then picked.  Traced as a `members` span (`k`
-        detectors, `slots`)."""
+                 slots: np.ndarray, t_max: int):
+        """The ensemble's per-member columns of one call, for every
+        member slot at once: (members, K) per-detector flag counts,
+        (members, K) float32 score sums (None without scores) and the
+        read-only bitmask rows (each member's first n samples valid;
+        None without `collect`).  Counts the per-detector flag
+        counters.  The engine zeroes the bitmask and the scores past
+        each slot's valid rows, so columns are reduced whole, up to the
+        call's longest `t_max`: gathered first when the call holds few
+        of the pool's slots, else reduced first and then picked.
+        Traced as a `members` span (`k` detectors, `slots`)."""
         tracer = self.tracer
         names = self._det_names
         with (tracer.span("members", device=True, tick=self.tick_no,
@@ -886,22 +1008,43 @@ class BatchingScheduler:
                 total = int(counts[d].sum())
                 if total:
                     self._det_counter(det).inc(total)
-            counts = counts.T.tolist()
+            sums = None
             if scores is not None:
                 # row d of the score block is detectors[d]'s float
                 # score stream
                 sc = scores[:, :t_max, slots] if few else scores[:, :t_max]
-                sums = sc.sum(axis=1)[:, pick].T.tolist()
-            rows = (np.ascontiguousarray((bits if few else bits[:, slots]).T)
-                    if self.collect else None)
-            out = []
-            for i, row in enumerate(counts):
-                det_counts = {det: c for det, c in zip(names, row) if c}
-                det_sums = (dict(zip(names, sums[i]))
-                            if scores is not None else None)
-                out.append((det_counts, det_sums,
-                            rows[i] if rows is not None else None))
-            return out
+                sums = sc.sum(axis=1)[:, pick].T
+            rows = None
+            if self.collect:
+                rows = np.ascontiguousarray(
+                    (bits if few else bits[:, slots]).T)
+                rows.flags.writeable = False
+            return counts.T, sums, rows
+
+    def _fold(self, run: _Run) -> None:
+        """Copy a running request's counter row into its RequestStats."""
+        self._c_folds.inc()
+        acc, r, st = self._rows, run.row, run.stats
+        st.samples = int(acc.samples[r])
+        st.flags = int(acc.flags[r])
+        st.prefill_chunks = int(acc.prefill_chunks[r])
+        st.decode_steps = int(acc.decode_steps[r])
+        if self._ensemble:
+            names = self._det_names
+            st.det_flags.clear()
+            st.det_flags.update(
+                (d, c) for d, c in zip(names, acc.det_flags[r].tolist())
+                if c)
+            if acc.scored[r]:
+                st.det_scores.update(zip(names, acc.det_scores[r].tolist()))
+
+    def _fold_rid(self, rid: str) -> None:
+        """`_fold` for a `stats_by_rid` read: running requests only
+        (queued ones have no row, finished ones were folded at
+        completion)."""
+        run = self.runs.get(rid)
+        if run is not None:
+            self._fold(run)
 
     def _flush(self, events: Optional[dict] = None) -> None:
         """Retire every in-flight call (the consume-side sync)."""
@@ -949,7 +1092,7 @@ class BatchingScheduler:
             # collide across shards, the pair never does.
             def _free():
                 fenced = {r.place for i in self._inflight
-                          for r, _, _ in i.members}
+                          for r in i.runs}
                 return [r for r in ready if r.place not in fenced]
             free = _free()
             while not free and self._inflight:
@@ -1003,6 +1146,8 @@ class BatchingScheduler:
             run = self.runs.pop(rid)
             del self._closing[rid]
             run.phase = DONE
+            self._fold(run)
+            self._rows.give(run.row)
             st = run.stats
             st.done_tick = self.tick_no
             if self._sharded:
@@ -1022,7 +1167,7 @@ class BatchingScheduler:
             while len(self._finished) > self.keep_finished:
                 old = next(iter(self._finished))  # oldest completion
                 del self._finished[old]
-                self.stats_by_rid.pop(old, None)
+                self._stats.pop(old, None)
                 self._note_evicted(old)
                 self.events.publish("evicted", self.tick_no, old)
 
@@ -1118,14 +1263,17 @@ class BatchingScheduler:
                 "outlier": cat(run.outlier_parts, bool)}
 
     def telemetry(self, rid: str) -> RequestStats:
-        """The request's `RequestStats` (sample/flag counts final only
-        after its in-flight calls retire — synced here)."""
-        st = self.stats_by_rid.get(rid)
+        """The request's `RequestStats`: a running request's in-flight
+        calls are retired first (the sync point) and its counter row
+        folded in, so the counts cover every sample dispatched so far."""
+        st = self._stats.get(rid)
         if st is None:
             raise self._missing(rid)
         run = self.runs.get(rid)
-        if run is not None and run.inflight:
-            self._flush()  # consume-side sync point
+        if run is not None:
+            if run.inflight:
+                self._flush()  # consume-side sync point
+            self._fold(run)
         return st
 
     def request_phase(self, rid: str) -> str:
@@ -1135,7 +1283,7 @@ class BatchingScheduler:
             return run.phase
         if rid in self._finished:
             return DONE
-        if rid in self.stats_by_rid:
+        if rid in self._stats:
             return QUEUED
         raise self._missing(rid)
 
@@ -1177,6 +1325,7 @@ class BatchingScheduler:
                "pipeline_depth": self.pipeline_depth,
                "short_ticks": self.short_ticks,
                "chunk_latency": lat, "classes": classes,
+               "stats_folds": int(self._c_folds.value),
                "h2d_bytes": int(self._c_h2d.value),
                "d2h_bytes": int(self._c_d2h.value),
                "programs": self.pool.programs(),

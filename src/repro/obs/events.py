@@ -24,7 +24,9 @@ alternative for in-process hooks (`serve_streams(on_event=...)`):
 the callback runs synchronously at publish time, in retirement order.
 
 Publishing is zero-cost with no consumers: `bus.active` is False and
-the scheduler skips event assembly entirely.
+the scheduler skips event assembly entirely.  `publish_many` delivers
+the `chunk_retired` events of one retired call in one pass: listeners
+are resolved once, and each member still gets an `Event` of its own.
 """
 from __future__ import annotations
 
@@ -37,7 +39,7 @@ from typing import Callable, Iterator, List, Optional
 __all__ = ["Event", "EventBus", "Subscription"]
 
 
-@dataclass
+@dataclass(slots=True)
 class Event:
     """One structured scheduler event.
 
@@ -157,3 +159,23 @@ class EventBus:
         for cb in list(self._callbacks):
             cb(ev)
         return ev
+
+    def publish_many(self, kind: str, tick: int, items) -> int:
+        """Deliver one event per `(rid, data)` of `items`, in order, with
+        consecutive `seq`: each event reaches every subscription and
+        callback before the next is built.  The listeners are those
+        registered when the call starts.  `items` is not iterated when
+        nothing is listening; returns the number of events delivered."""
+        if not self.active:
+            return 0
+        subs, cbs = list(self._subs), list(self._callbacks)
+        n = 0
+        for rid, data in items:
+            ev = Event(kind=kind, seq=next(self._seq), tick=tick, rid=rid,
+                       data=data)
+            for sub in subs:
+                sub._push(ev)
+            for cb in cbs:
+                cb(ev)
+            n += 1
+        return n

@@ -10,6 +10,9 @@ perturbing live tenants; a full pool and a full admission queue must
 be explicit backpressure, never silent drops; and the scheduler's
 retention caps (`keep_finished`, `call_log_len`) must actually evict.
 """
+import time
+from dataclasses import asdict
+
 import numpy as np
 import pytest
 
@@ -577,7 +580,7 @@ def _run_depth(depth, specs, prios=None, backend="pallas-q",
         sched.step()
         if check_fence:
             slots = [s for inf in sched._inflight
-                     for _, s, _ in inf.members]
+                     for s in inf.slots.tolist()]
             assert len(slots) == len(set(slots)), \
                 f"slot fenced twice in flight at tick {tick}: {slots}"
             assert len(sched._inflight) <= depth + 1
@@ -679,3 +682,300 @@ def test_pipeline_depth_bounds_inflight_queue():
         sched.step()
         assert len(sched._inflight) <= 2
     sched._flush()
+
+
+# ------------------------------------------- columnar call accounting
+class _PerMemberLoop(BatchingScheduler):
+    """The oracle: a retired call accounted member by member, as the
+    scheduler did before its counters became rows — each member's
+    `RequestStats` updated in place, per-detector dicts merged, its
+    rows copied, one publish per member."""
+
+    def _fold(self, run):
+        pass  # the per-member loop keeps RequestStats current itself
+
+    def _account(self, inf, outlier, ecc, scores, events):
+        wall = (inf.sync_wall if inf.sync_wall is not None
+                else time.perf_counter() - inf.t0)
+        members = list(zip(inf.runs, inf.slots.tolist(), inf.ns.tolist()))
+        retired = int(sum(n for _, _, n in members))
+        self.call_log.append({
+            "kind": "fused", "t": inf.t_len, "slots": len(members),
+            "retired": retired, "wall_s": wall,
+            "sync": inf.sync_wall is not None})
+        self._h_wall.observe(wall * 1e3, weight=max(retired, 1))
+        self._c_samples.inc(retired)
+        flagged = (events["flagged"] if events is not None
+                   else self._deferred_flagged)
+        slots = np.array([s for _, s, _ in members], np.intp)
+        t_max = max(n for _, _, n in members)
+        cols = np.ascontiguousarray(outlier[:t_max, slots].T)
+        n_flags = cols.sum(axis=1).tolist()
+        names = self._det_names
+        if self._ensemble:
+            # gathered first when the call holds few of the pool's
+            # slots, else reduced first and then picked
+            few = 4 * len(slots) < ecc.shape[1]
+            pick = slice(None) if few else slots
+            bits = ecc[:t_max, slots] if few else ecc[:t_max]
+            counts = np.stack([np.count_nonzero(bits & (1 << d), axis=0)
+                               for d in range(len(names))])[:, pick]
+            for d, det in enumerate(names):
+                if int(counts[d].sum()):
+                    self._det_counter(det).inc(int(counts[d].sum()))
+            counts = counts.T.tolist()
+            sc = (None if scores is None else
+                  scores[:, :t_max, slots] if few else scores[:, :t_max])
+            sums = (sc.sum(axis=1)[:, pick].T.tolist()
+                    if scores is not None else None)
+            rows = np.ascontiguousarray((bits if few else bits[:, slots]).T)
+        for i, (run, slot, n) in enumerate(members):
+            st = run.stats
+            st.samples += n
+            if len(st.chunk_latency_s) < self.latency_log_len:
+                st.chunk_latency_s.append((wall, n))
+            col = cols[i, :n]
+            st.flags += n_flags[i]
+            if n_flags[i]:
+                flagged.append(run.req.rid)
+                self._c_flags.inc(n_flags[i])
+            det_counts = det_sums = None
+            if self._ensemble:
+                det_counts = {d: c for d, c in zip(names, counts[i]) if c}
+                for det, c in det_counts.items():
+                    st.det_flags[det] = st.det_flags.get(det, 0) + c
+                if sums is not None:
+                    det_sums = dict(zip(names, sums[i]))
+                    for det, s in det_sums.items():
+                        st.det_scores[det] = st.det_scores.get(det, 0.0) + s
+            if n > 1:
+                st.prefill_chunks += 1
+            else:
+                st.decode_steps += 1
+            if self.collect:
+                ecc_col = (rows[i, :n].copy() if self._ensemble
+                           else ecc[:n, slot].copy())
+                run.ecc_parts.append(ecc_col)
+                run.outlier_parts.append(col)
+            if self.events.active:
+                data = {"slot": slot, "n": n, "flags": n_flags[i],
+                        "dispatch_tick": inf.tick, "outlier": col.copy()}
+                if inf.shard is not None:
+                    data["shard"] = inf.shard
+                if self.collect:
+                    data["ecc"] = ecc_col.copy()
+                if det_counts is not None:
+                    data["det_flags"] = det_counts
+                    data["detectors"] = names
+                if det_sums is not None:
+                    data["det_scores"] = det_sums
+                self.events.publish("chunk_retired", self.tick_no,
+                                    run.req.rid, **data)
+            run.inflight -= 1
+        self._g_inflight.set(len(self._inflight))
+
+
+K5 = ("teda", "rde", "zscore", "hst", "teda-q")
+SUBSETS = [None, ("teda", "rde"), ("hst",), ("zscore", "teda-q", "rde")]
+
+
+def _skewed_rids(n):
+    """n rids that the two-shard ring routes to shard 0."""
+    from repro.engine import ShardedPool
+    probe = ShardedPool("scan", shards=2, buckets=(8,))
+    return [rid for rid in (f"skew{i}" for i in range(200))
+            if probe.route(rid) == 0][:n]
+
+
+COLUMNAR = {
+    # case: (backend, scheduler options, workload options)
+    "scan-collect": ("scan", dict(collect=True), {}),
+    "scan-nocollect": ("scan", dict(collect=False), {}),
+    "pallas-q-collect": ("pallas-q", dict(collect=True), {}),
+    "pallas-q-nocollect": ("pallas-q", dict(collect=False), {}),
+    "ensemble-subsets-collect": (
+        "ensemble", dict(collect=True, detectors=K5), dict(subsets=True)),
+    "ensemble-subsets-nocollect": (
+        "ensemble", dict(collect=False, detectors=K5), dict(subsets=True)),
+    "sharded-migration": (
+        "scan", dict(collect=True, shards=2, rebalance_every=2,
+                     buckets=(8,)), dict(skew=True, seed=4)),
+    "telemetry-midstream": (
+        "pallas-q", dict(collect=True), dict(reads=(3, 6, 9))),
+    "row-reuse": ("scan", dict(collect=True, buckets=(2,)), dict(n=7)),
+}
+
+
+def _serve_columnar(sched, specs, subsets=False, reads=()):
+    """`_serve_interleaved`, with per-tenant detector subsets and
+    `telemetry()` snapshots of every running request at the given
+    ticks; returns the events and the snapshots."""
+    sub = sched.subscribe(maxlen=1 << 16)
+    order = list(specs)
+    fed = {rid: 0 for rid in specs}
+    closed = set()
+    snaps = []
+    for tick in range(500):
+        if tick < len(order):
+            rid = order[tick]
+            h, live, m = specs[rid]
+            kw = {}
+            if subsets:
+                i = order.index(rid)
+                kw = dict(detectors=SUBSETS[i % len(SUBSETS)],
+                          vote="any" if i % 2 else None)
+            assert sched.submit(Request(rid, h, m=m, **kw))
+            if not live.size:
+                sched.close(rid)
+                closed.add(rid)
+        for rid, (h, live, m) in specs.items():
+            if rid not in sched.stats_by_rid or rid in closed:
+                continue
+            if fed[rid] < live.size:
+                sched.feed(rid, live[fed[rid]:fed[rid] + 1])
+                fed[rid] += 1
+            if fed[rid] == live.size:
+                sched.close(rid)
+                closed.add(rid)
+        sched.step()
+        if tick in reads:
+            snaps.append({rid: _stats_values(sched.telemetry(rid))
+                          for rid in sorted(sched.runs)})
+        if sched.completed == len(specs):
+            break
+    sched.drain()
+    return sub.poll(), snaps
+
+
+def _stats_values(st):
+    """A RequestStats as comparable values: score sums by their bits,
+    latency pairs by their sample counts (walls differ run to run)."""
+    d = asdict(st)
+    d["det_scores"] = {k: float(v).hex() for k, v in d["det_scores"].items()}
+    d["chunk_latency_s"] = [n for _, n in d["chunk_latency_s"]]
+    return d
+
+
+def _assert_same_payload(key, got, want, rid):
+    if isinstance(want, np.ndarray):
+        assert isinstance(got, np.ndarray) and not got.flags.writeable
+        assert got.dtype == want.dtype, (rid, key)
+        np.testing.assert_array_equal(got, want, err_msg=f"{rid} {key}")
+    elif key == "det_scores":
+        assert {k: float(v).hex() for k, v in got.items()} == {
+            k: float(v).hex() for k, v in want.items()}, rid
+    else:
+        assert got == want, (rid, key)
+
+
+@pytest.mark.parametrize("case", list(COLUMNAR))
+def test_columnar_accounting_matches_per_member_loop(case):
+    """Every event, `results()`, `RequestStats` and flag counter of the
+    columnar accounting equals what the per-member loop gives, score
+    sums bit for bit: Q and float backends, the ensemble with
+    per-tenant subsets, `collect` on and off, a sharded pool migrating
+    mid-stream, `telemetry()` reads mid-stream, and counter rows reused
+    by later admissions."""
+    backend, opts, wl = COLUMNAR[case]
+    opts = dict(opts)
+    opts.setdefault("buckets", (2, 4))
+    specs = _workload(wl.get("n", 6), seed=wl.get("seed", 31))
+    if wl.get("skew"):
+        specs = dict(zip(_skewed_rids(len(specs)), specs.values()))
+    runs = []
+    for cls in (BatchingScheduler, _PerMemberLoop):
+        sched = cls(backend, fmt=FMT, chunk_t=8, **opts)
+        evs, snaps = _serve_columnar(sched, specs,
+                                     subsets=wl.get("subsets", False),
+                                     reads=wl.get("reads", ()))
+        runs.append((sched, evs, snaps))
+    (new, evs, snaps), (old, want_evs, want_snaps) = runs
+    assert len(evs) == len(want_evs) > 0
+    for ev, want in zip(evs, want_evs):
+        assert (ev.kind, ev.seq, ev.tick, ev.rid) == (
+            want.kind, want.seq, want.tick, want.rid)
+        assert list(ev.data) == list(want.data), ev.kind
+        for key, value in want.data.items():
+            _assert_same_payload(key, ev.data[key], value, ev.rid)
+    assert snaps == want_snaps
+    if wl.get("reads"):
+        assert any(snaps), "no request was running at a read"
+    for rid in specs:
+        assert _stats_values(new.stats_by_rid[rid]) == _stats_values(
+            old.stats_by_rid[rid]), rid
+        if opts["collect"]:
+            got, want = new.results(rid), old.results(rid)
+            for key in ("ecc", "outlier"):
+                assert got[key].dtype == want[key].dtype
+                np.testing.assert_array_equal(got[key], want[key])
+    s_new, s_old = new.stats(), old.stats()
+    assert s_new.get("detector_flags") == s_old.get("detector_flags")
+    assert int(new._c_flags.value) == int(old._c_flags.value)
+    assert new.stats()["completed"] == len(specs)
+    if wl.get("skew"):
+        assert new.pool.migrations > 0  # streams moved mid-stream
+    if case == "row-reuse":
+        assert new._rows.size < len(specs)  # completed rows re-taken
+
+
+def test_stats_folds_counted_per_read():
+    """`stats_folds` counts copies of a running request's row into its
+    RequestStats: none on a tick nobody reads, one per completion, one
+    per `telemetry()` or `stats_by_rid` read of a running request, none
+    for a queued or finished one or a membership test."""
+    sched = _mk_sched("scan", buckets=(2,), queue_limit=8,
+                      measure_latency=True)
+    folds = lambda: sched.stats()["stats_folds"]
+    rng = np.random.default_rng(5)
+    data = lambda n: rng.normal(size=(n,)).astype(np.float32)
+    for i in range(3):
+        sched.submit(Request(f"r{i}", data(20)))
+    sched.close("r0")
+    for _ in range(4):                     # r0 drains, r1 stays open
+        sched.step()
+        assert folds() == sched.completed  # the silent path
+    assert sched.completed == 1 and folds() == 1
+    assert sched.telemetry("r1").samples == 20
+    assert folds() == 2                    # a running request
+    sched.feed("r1", data(3))
+    sched.step()
+    st = sched.stats_by_rid["r1"]
+    assert folds() == 3 and st.samples == 23
+    sched.submit(Request("r3", data(4)))   # r1 and r2 hold the pool
+    sched.step()
+    assert sched.request_phase("r3") == "queued"
+    sched.telemetry("r0")                  # finished: folded already
+    sched.stats_by_rid["r3"]               # queued: no row yet
+    assert "r1" in sched.stats_by_rid and len(list(sched.stats_by_rid))
+    assert folds() == 3
+    assert int(sched.registry.get("sched_stats_folds_total").labels(
+        sched=sched.name).value) == 3
+    for rid in ("r1", "r2", "r3"):
+        sched.close(rid)
+    sched.drain()
+    assert folds() == 3 + 3                # one per completion
+    assert sched.stats_by_rid["r3"].samples == 4
+    assert folds() == 6
+
+
+def test_counter_rows_grow_and_reuse():
+    """`_Rows` keeps every row's counters when it doubles, and a row
+    given back comes back zeroed."""
+    from repro.launch.batching import _Rows
+    rows = _Rows(k=2, capacity=2)
+    taken = [rows.take() for _ in range(5)]   # grows 2 -> 4 -> 8
+    assert taken == list(range(5)) and rows.samples.shape == (8,)
+    idx = np.array(taken)
+    rows.samples[idx] += idx + 1
+    rows.det_scores[idx] += 0.5
+    rows.scored[idx] = True
+    for _ in range(4):
+        rows.take()                           # grows 8 -> 16
+    assert rows.det_flags.shape == (16, 2)
+    np.testing.assert_array_equal(rows.samples[:5], [1, 2, 3, 4, 5])
+    np.testing.assert_array_equal(rows.det_scores[:5], 0.5)
+    rows.give(3)
+    assert rows.take() == 3 and rows.size == 9
+    assert (rows.samples[3], rows.det_scores[3, 1], rows.scored[3]) == (
+        0, 0.0, False)
+    assert rows.samples[4] == 5

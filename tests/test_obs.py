@@ -269,6 +269,31 @@ def test_event_bus_attach_callback_and_iter():
     assert len(seen) == 2
 
 
+def test_event_bus_publish_many_one_event_each():
+    """`publish_many` delivers one `Event` per item, in order, with
+    consecutive seqs after earlier publishes, to every subscription and
+    callback; with nobody listening it never touches its items."""
+    bus = EventBus()
+
+    def untouched():
+        raise AssertionError("items iterated on the silent path")
+        yield
+
+    assert bus.publish_many("chunk_retired", 0, untouched()) == 0
+    seen = []
+    bus.attach(seen.append)
+    sub = bus.subscribe()
+    bus.publish("admitted", 1, "a")
+    items = [("a", {"n": 1}), ("b", {"n": 2}), ("a", {"n": 3})]
+    assert bus.publish_many("chunk_retired", 2, iter(items)) == 3
+    evs = sub.poll()
+    assert evs == seen
+    assert [e.seq for e in evs] == [0, 1, 2, 3]
+    assert [(e.kind, e.tick, e.rid, e.data) for e in evs[1:]] == [
+        ("chunk_retired", 2, rid, data) for rid, data in items]
+    assert not hasattr(evs[0], "__dict__")  # slotted: one object each
+
+
 # ----------------------------------------- scheduler/pool integration
 def _run_workload(sched, specs, feed_steps=True):
     """Submit, trickle-feed, close and drain a {rid: (hist, live)} mix."""
@@ -581,3 +606,49 @@ def test_serve_streams_on_event_and_metrics():
     assert "sched_call_wall_ms" in snap
     assert snap["sched_call_wall_ms"]["samples"][0]["count"] > 0
     json.dumps(snap)
+
+
+@pytest.mark.parametrize("backend,collect", [("pallas-q", True),
+                                             ("scan", False),
+                                             ("ensemble", True)])
+def test_chunk_retired_payloads_are_readonly_views(backend, collect):
+    """A call's `chunk_retired` payloads are read-only views of the
+    rows it gathered, not copies; its events carry consecutive seqs in
+    the call's member (admission) order; and the payloads still
+    concatenate to `results()`."""
+    specs = _specs(5, seed=23)
+    kw = dict(detectors=K5) if backend == "ensemble" else {}
+    sched = BatchingScheduler(backend, fmt=FMT, buckets=(2, 4),
+                              chunk_t=4, collect=collect, **kw)
+    sub = sched.subscribe(maxlen=1 << 14)
+    _run_workload(sched, specs)
+    evs = sub.poll()
+    assert [e.seq for e in evs] == list(range(evs[0].seq,
+                                              evs[0].seq + len(evs)))
+    admitted = [e.rid for e in evs if e.kind == "admitted"]
+    chunks = [e for e in evs if e.kind == "chunk_retired"]
+    assert chunks
+    keys = ("outlier", "ecc") if collect else ("outlier",)
+    for e in chunks:
+        assert ("ecc" in e.data) == collect
+        for key in keys:
+            a = e.data[key]
+            assert a.base is not None and not a.flags.writeable, key
+            assert a.shape == (e.data["n"],)
+            with pytest.raises(ValueError):
+                a[0] = a[0]
+    calls = {}
+    for e in chunks:
+        calls.setdefault(e.data["dispatch_tick"], []).append(e)
+    for group in calls.values():
+        seqs = [e.seq for e in group]
+        assert seqs == list(range(seqs[0], seqs[0] + len(group)))
+        order = [admitted.index(e.rid) for e in group]
+        assert order == sorted(order)
+    if collect:
+        for rid in specs:
+            mine = [e for e in chunks if e.rid == rid]
+            res = sched.results(rid)
+            for key in keys:
+                np.testing.assert_array_equal(
+                    np.concatenate([e.data[key] for e in mine]), res[key])
