@@ -1,17 +1,17 @@
 """Bit-accurate simulation analog: word-length sweep vs float64 oracle.
 
 The paper validates its FPGA datapath with a bit-accurate fixed-point
-simulation.  This benchmark regenerates that study for the repo: for
+simulation.  This example regenerates that study for the repo: for
 each QFormat (WL in {16, 24, 32}, FL swept) it runs the integer TEDA
 datapath over a DAMADICS fault stream and a synthetic spike stream and
 reports eccentricity error + outlier-verdict agreement against the
 float64 software oracle.
 
-  PYTHONPATH=src python -m benchmarks.bench_bitaccurate \
+  PYTHONPATH=src python examples/paper_bitaccurate.py \
       [--t-len 3000] [--out experiments/bitaccurate/sweep.json]
 
-Prints ``name,us_per_call,derived`` CSV rows (the run.py harness
-format) and writes the full sweep as JSON.
+Prints ``name,us_per_call,derived`` CSV rows and writes the full sweep
+as JSON.
 """
 from __future__ import annotations
 

@@ -4,6 +4,8 @@ Runs TEDA (m = 3, threshold 5/k, exactly the paper's setting) over the
 seven synthetic DAMADICS-like fault items and reports hit/latency/false
 alarms for each — plus the eq-forms cross-check (lax.scan vs associative
 scan vs Pallas kernel produce identical verdict sets).
+
+  PYTHONPATH=src python examples/paper_detection.py
 """
 from __future__ import annotations
 

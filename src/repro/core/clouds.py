@@ -72,11 +72,13 @@ def clouds_step(state: CloudState, x: jnp.ndarray, m: float = 3.0
     k1, mean1, var1, zeta = _tentative(state, x)
     thr = (m * m + 1.0) / (2.0 * k1)
     # pure eq (5)/(6)-complement membership. Note the detectability
-    # bound (DESIGN.md §7): a cloud younger than m^2 samples cannot
-    # reject, so the classifier targets the TEDAClass streaming regime —
-    # concept drift with each regime lasting > m^2 samples (as in [4]'s
-    # industrial-fault experiments). Rapidly interleaved regimes would
-    # need the sigma-gap extension of [6].
+    # bound: eq (3) absorbs the sample, so zeta <= (k+1)/(2k) and eq (6)
+    # cannot trip while k <= m^2 (tests/test_teda.py); a cloud younger
+    # than m^2 samples cannot reject, so the classifier targets the
+    # TEDAClass streaming regime — concept drift with each regime
+    # lasting > m^2 samples (as in [4]'s industrial-fault experiments).
+    # Rapidly interleaved regimes would need the sigma-gap extension of
+    # [6].
     join = jnp.logical_and(active, zeta <= thr)
 
     any_join = jnp.any(join)

@@ -13,10 +13,10 @@ is a `jnp.where` mask, so it composes with pjit/shard_map and costs a few
 hundred scalar flops per step.  The monitored channels are packed
 `repro.engine` state (one slot per telemetry channel) advanced with the
 engine's single-sample fast path — the same per-stream contract the
-serving monitor and the chunked StreamEngine use.
+serving gateway and the chunked StreamEngine use.
 
 Also provides a host-side `StragglerDetector` (TEDA over per-step wall
-times across hosts) used by the launcher for straggler mitigation.
+times across hosts) for straggler detection.
 """
 from __future__ import annotations
 
@@ -79,8 +79,8 @@ def guard_step(state: GuardState, metrics: jnp.ndarray, cfg: GuardConfig
     Non-finite telemetry (NaN/inf loss or grad norm) is always an outlier.
     With `exclude_outliers`, flagged samples do not contaminate the TEDA
     statistics (the state update is rolled back), so a run of spikes stays
-    detectable — this extends the paper (which always absorbs samples) and
-    is ablated in benchmarks/bench_detection.py.
+    detectable — this extends the paper (which always absorbs samples;
+    tests/test_guard.py holds both behaviours).
     """
     eng = _engine()
     finite = jnp.isfinite(metrics)
@@ -117,10 +117,10 @@ def apply_guard(skip: jnp.ndarray, new_tree, old_tree):
 class StragglerDetector:
     """Host-side TEDA over per-step wall-times (straggler mitigation).
 
-    The launcher feeds it one duration per step (or per-host durations in
-    multi-controller deployments); `check()` returns True when the latest
-    step is eccentric per eq (6) — the signal used to trigger host
-    replacement / checkpoint handoff at fleet scale.
+    A training loop feeds it one duration per step (or per-host
+    durations in multi-controller deployments); `check()` returns True
+    when the latest step is eccentric per eq (6) — the signal used to
+    trigger host replacement / checkpoint handoff at fleet scale.
     """
 
     def __init__(self, m: float = 3.0, warmup: int = 10):

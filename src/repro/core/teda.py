@@ -133,8 +133,8 @@ def teda_stream(x: jnp.ndarray, m: float | jnp.ndarray = 3.0,
 def teda_numpy_loop(x, m: float = 3.0):
     """Plain-Python reference loop (the paper's 'software platform').
 
-    Used by benchmarks/bench_platforms.py as the Table-5 software baseline
-    and by tests as an independent oracle. x: numpy (T, N).
+    The paper's Table-5 software baseline, and an independent oracle
+    for the tests. x: numpy (T, N).
     """
     import numpy as np
 

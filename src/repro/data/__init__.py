@@ -1,4 +1,3 @@
-"""Data pipeline: synthetic token streams + DAMADICS-like fault streams."""
-from repro.data.stream import PrefetchIterator, TokenStream, batch_stats
+"""Data: DAMADICS-like fault streams."""
 from repro.data.damadics import (TABLE2, FaultWindow, base_signals,
                                  detection_report, inject, make_benchmark)

@@ -16,7 +16,7 @@ so a scheduler can freeze slots that have no data this step without
 releasing them (the continuous-batching suspend, `launch/batching.py`).
 
 With a `mesh`, chunk processing fans out over the channel axis via
-`shard_map` (`sharding.rules.make_channel_fanout`) — channels are
+`shard_map` (`make_channel_fanout` below) — channels are
 independent, so multi-device scale needs no collectives.  With a
 `device`, the packed state is committed to that one device, so every
 call on it runs there (one shard per chip in `engine/sharded.py`).
@@ -28,6 +28,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.teda import TedaState
 from repro.engine.backends import get_backend
@@ -36,6 +37,29 @@ from repro.engine.state import (EngineState, engine_init, engine_process,
 from repro.obs import MetricsRegistry, auto_name
 
 __all__ = ["StreamEngine"]
+
+
+def make_channel_fanout(fn, mesh: Mesh, axis_name: str = "data"):
+    """shard_map fan-out of an independent-channel stream processor.
+
+    `fn(x, k, mean, var, vlen, m) -> ((k', mean', var'),
+    (ecc, outlier))` — the `repro.engine` backend contract: x is (T, C)
+    with C independent univariate streams on the lane axis, the state
+    rows (and the per-slot valid-length vector `vlen` and threshold
+    vector `m`) are (C,) vectors, and the per-sample outputs are
+    (T, C).  Channels are independent TEDA
+    modules (the paper's replicated-module scaling, §5.2.1), so the
+    fan-out needs no collectives: each device runs `fn` on its C/D
+    channel slice.  The caller must keep C divisible by the axis size
+    (StreamEngine asserts this).
+    """
+    vec = P(axis_name)
+    row = P(None, axis_name)
+    return jax.shard_map(
+        fn, mesh=mesh, check_vma=False,
+        in_specs=(row, vec, vec, vec, vec, vec),
+        out_specs=((vec, vec, vec), (row, row)),
+    )
 
 
 @jax.jit
@@ -159,7 +183,6 @@ class StreamEngine:
 
         self._mesh = mesh
         if mesh is not None:
-            from repro.sharding.rules import make_channel_fanout
             n_shards = dict(mesh.shape)[axis_name]
             if self.capacity % n_shards:
                 raise ValueError(

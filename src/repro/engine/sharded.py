@@ -33,7 +33,7 @@ is committed there, so every call of the shard runs there — any
 backend, the detector ensemble included).  A larger group gets its own
 single-axis `jax.sharding.Mesh` and the per-shard engines fan
 processing out over the channel axis via
-`sharding.rules.make_channel_fanout` — the bucket ladder must stay
+`engine.engine.make_channel_fanout` — the bucket ladder must stay
 divisible by the per-shard device count so every bucket capacity
 shards evenly.  Without `devices=`, shards share the default device
 (the CPU-only CI case: `XLA_FLAGS=

@@ -8,8 +8,8 @@ an `active` occupancy mask, so every slot is ragged: its own stream
 position, recyclable for a new tenant mid-flight via
 `engine_attach` / `engine_detach` / `engine_reset`.
 
-Everything here is pure and jittable — `core/guard.py` and
-`launch/serve.py` run `engine_step` inside compiled train/decode steps.
+Everything here is pure and jittable — `core/guard.py` runs
+`engine_step` inside a compiled train step.
 This module is a leaf (it depends only on `core/teda.py`): the backend
 registry and the stateful `StreamEngine` wrapper live one level up in
 `engine/backends.py` / `engine/engine.py`.
@@ -239,9 +239,9 @@ def engine_step(state: EngineState, x: jnp.ndarray,
                 ) -> Tuple[EngineState, TedaOutput]:
     """Single-sample fast path: one packed update for x (C,).
 
-    The T=1 analog of `engine_process` for in-loop monitors (the train
-    guard, the decode monitor) — one `teda_step` on the packed vectors,
-    cheap enough to live inside a jitted train/decode step.  Float-state
+    The T=1 analog of `engine_process` for in-loop monitors (the
+    training-loss guard) — one `teda_step` on the packed vectors,
+    cheap enough to live inside a jitted train step.  Float-state
     only (the Q datapath goes through `engine_process`).
     """
     if jnp.issubdtype(state.k.dtype, jnp.integer):

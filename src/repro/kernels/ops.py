@@ -138,13 +138,13 @@ def teda_scan_verdict(x: jnp.ndarray, m: float | jnp.ndarray = 3.0,
     """Slim-output TEDA kernel: (final state, {ecc, outlier}).
 
     HBM write traffic per sample drops from 16B (mean+var+ecc+i32 flag)
-    to 5B (ecc + i8 flag) — the memory-roofline optimization recorded in
-    EXPERIMENTS.md §Perf.  The kernel masks each channel's ragged tail
-    against its valid length, so a bit-exact final state is returned
-    for every T — this is the engine's float hot path.  `m` may be
-    per-channel (C,); eq (6) is then re-evaluated outside the kernel
-    (see module docs).  `valid_lens` may be a scalar or per-channel
-    (C,) vector of leading valid row counts (see module docs).
+    to 5B (ecc + i8 flag), for a memory-bound kernel.  The kernel masks
+    each channel's ragged tail against its valid length, so a bit-exact
+    final state is returned for every T — this is the engine's float
+    hot path.  `m` may be per-channel (C,); eq (6) is then re-evaluated
+    outside the kernel (see module docs).  `valid_lens` may be a scalar
+    or per-channel (C,) vector of leading valid row counts (see module
+    docs).
     `block_c` tiles the channel axis into parallel grid strips.
     """
     if interpret is None:

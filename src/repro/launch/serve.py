@@ -1,51 +1,26 @@
-"""Serving gateway: continuous-batching TEDA detection + LM monitoring.
+"""Serving gateway: continuous-batching TEDA detection.
 
-Two entry points, both driven by the `launch/batching.py` scheduler
-(admission queue, chunked prefill, per-request telemetry, backpressure
-when every capacity bucket is full):
+`serve_streams` drives the `launch/batching.py` scheduler (admission
+queue, chunked prefill, per-request telemetry, backpressure when every
+capacity bucket is full): tenant streams (history + live samples,
+per-tenant sensitivity `m`) arrive on a schedule, attach to engine
+slots, and are served continuously.
 
-  * `serve_streams` — the generic detection gateway: tenant streams
-    (history + live samples, per-tenant sensitivity `m`) arrive on a
-    schedule, attach to engine slots, and are served continuously.
-    This is the workload driver behind `benchmarks/bench_serving.py`.
-
-        PYTHONPATH=src python -m repro.launch.serve --mode streams \
-            --requests 16 --history 256 --live 32 --backend pallas
-
-  * `serve` — the LM demo: prefills a prompt batch, then decodes while
-    per-request telemetry (logit entropy, max-logit) streams through
-    the detection gateway — prompt-phase telemetry replays as chunked
-    prefill (the monitor is warmed up on the tenant's own history), and
-    decode-phase telemetry rides the per-tick trickle.  Flagged
-    requests surface the way a production gateway would quarantine
-    degenerate generations.
-
-        PYTHONPATH=src python -m repro.launch.serve --arch qwen2-7b \
-            --scale tiny --batch 4 --prompt-len 32 --gen 32
-
-The telemetry itself (log-softmax entropy, max-logit) is computed
-*inside* the jitted decode step — the Python loop threads device
-arrays and hands the host-side scheduler one small (B, 2) array per
-generated token.
+    PYTHONPATH=src python -m repro.launch.serve \
+        --requests 16 --history 256 --live 32 --backend pallas
 """
 from __future__ import annotations
 
 import argparse
-import functools
 import time
 from collections import deque
 from typing import Optional, Sequence, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.registry import get_config
 from repro.launch.batching import BatchingScheduler, Request
 from repro.launch.compile_cache import use_compile_cache
-from repro.models import init_cache, init_lm_params, lm_decode_step
-
-N_CHANNELS = 2  # per-request telemetry: (entropy, max-logit)
 
 
 # --------------------------------------------------------------- gateway
@@ -206,123 +181,6 @@ def serve_streams(streams: Sequence[tuple],
     }
 
 
-# --------------------------------------------------------------- LM demo
-def make_decode_step(cfg, greedy: bool):
-    """Build the jitted decode step with fused telemetry extraction.
-
-    Returns the sampled token plus the (B,) entropy / max-logit rows
-    the monitor gateway consumes — no extra host round-trip beyond the
-    one that feeds the scheduler.
-    """
-
-    @functools.partial(jax.jit, donate_argnums=(3,))
-    def step(params, tok, pos, caches, key):
-        logits, caches = lm_decode_step(params, tok, pos, caches, cfg)
-        ent, mx = _telemetry(logits)
-        if greedy:
-            nxt = jnp.argmax(logits, axis=-1)
-        else:
-            nxt = jax.random.categorical(jax.random.fold_in(key, pos),
-                                         logits)
-        return nxt, caches, ent, mx
-
-    return step
-
-
-@jax.jit
-def _telemetry(logits):
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    ent = -jnp.sum(jnp.exp(logp) * logp, axis=-1)  # (B,)
-    mx = jnp.max(logits, axis=-1)                  # (B,)
-    return ent, mx
-
-
-def _monitor_buckets(n_slots: int) -> Tuple[int, ...]:
-    """Bucket ladder reaching at least n_slots (powers of two from 8)."""
-    ladder = [8]
-    while ladder[-1] < n_slots:
-        ladder.append(ladder[-1] * 2)
-    return tuple(ladder)
-
-
-def serve(cfg, batch: int, prompt_len: int, gen: int, m: float = 3.5,
-          seed: int = 0, greedy: bool = True, backend: str = "scan",
-          chunk_t: int = 16, fmt=None):
-    assert cfg.family != "encdec", "serve example targets decoder-only LMs"
-    key = jax.random.PRNGKey(seed)
-    params = init_lm_params(key, cfg)
-    prompts = jax.random.randint(key, (batch, prompt_len), 0, cfg.vocab)
-
-    max_seq = prompt_len + gen
-    caches = init_cache(cfg, batch, max_seq, dtype=jnp.float32)
-    decode = jax.jit(
-        lambda p, t, pos, c: lm_decode_step(p, t, pos, c, cfg),
-        donate_argnums=(3,))
-    step = make_decode_step(cfg, greedy)
-
-    # prefill by teacher-forcing the prompt through the decode path,
-    # banking per-token telemetry — it becomes the monitor's chunked-
-    # prefill history (the gateway warms up on the tenant's own prompt)
-    t0 = time.perf_counter()
-    prompt_tel = []
-    for i in range(prompt_len - 1):
-        logits, caches = decode(params, prompts[:, i], jnp.int32(i), caches)
-        prompt_tel.append(_telemetry(logits))
-    jax.block_until_ready(caches)
-    prefill_s = time.perf_counter() - t0
-    # (prompt_len-1, B, 2) on host, one request x channel stream each
-    # (empty for prompt_len == 1: the monitor starts cold)
-    hist = (np.stack([np.stack([np.asarray(e), np.asarray(x)], -1)
-                      for e, x in prompt_tel])
-            if prompt_tel else np.zeros((0, batch, N_CHANNELS),
-                                        np.float32))
-
-    # monitor gateway: one detection request per request x channel,
-    # admitted with the prompt history, fed one sample per decoded token
-    sched = BatchingScheduler(
-        backend, buckets=_monitor_buckets(batch * N_CHANNELS),
-        chunk_t=chunk_t, m=m, fmt=fmt,
-        queue_limit=batch * N_CHANNELS, collect=True)
-    rids = [(b, c) for b in range(batch) for c in range(N_CHANNELS)]
-
-    def rid(b, c):
-        return f"req{b}/ch{c}"
-
-    for b, c in rids:
-        ok = sched.submit(Request(rid(b, c), hist[:, b, c], m=m))
-        assert ok, "monitor queue sized to the request set"
-
-    outs = []
-    tok = prompts[:, -1]
-    t0 = time.perf_counter()
-    for i in range(gen):
-        pos = jnp.int32(prompt_len - 1 + i)
-        tok, caches, ent, mx = step(params, tok, pos, caches, key)
-        outs.append(tok)
-        tel = np.stack([np.asarray(ent), np.asarray(mx)], -1)  # (B, 2)
-        for b, c in rids:
-            sched.feed(rid(b, c), tel[b, c:c + 1])
-        sched.step()
-    for b, c in rids:
-        sched.close(rid(b, c))
-    sched.drain()
-    toks_out = np.stack([np.asarray(t) for t in outs], axis=1)
-    decode_s = time.perf_counter() - t0
-
-    # flag on decode-phase verdicts only (any channel): the prompt is
-    # the tenant's own baseline, not the generation under scrutiny
-    flagged = [b for b in range(batch)
-               if any(sched.results(rid(b, c))["outlier"][-gen:].any()
-                      for c in range(N_CHANNELS))]
-    return {
-        "tokens": toks_out,
-        "flagged_requests": flagged,
-        "prefill_tok_s": batch * (prompt_len - 1) / prefill_s,
-        "decode_tok_s": batch * gen / decode_s,
-        "monitor": sched.stats(),
-    }
-
-
 # ------------------------------------------------------------------- CLI
 def _demo_streams(n: int, history: int, live: int, seed: int = 0):
     """Synthetic tenant mix: drifting means, one loud anomaly burst,
@@ -341,12 +199,6 @@ def _demo_streams(n: int, history: int, live: int, seed: int = 0):
 
 def main(argv=None):
     ap = argparse.ArgumentParser()
-    ap.add_argument("--mode", default="lm", choices=["lm", "streams"])
-    ap.add_argument("--arch", default="qwen2-7b")
-    ap.add_argument("--scale", default="tiny", choices=["tiny", "full"])
-    ap.add_argument("--batch", type=int, default=4)
-    ap.add_argument("--prompt-len", type=int, default=32)
-    ap.add_argument("--gen", type=int, default=32)
     ap.add_argument("--backend", default="scan")
     ap.add_argument("--chunk-t", type=int, default=16)
     ap.add_argument("--requests", type=int, default=16)
@@ -375,56 +227,41 @@ def main(argv=None):
         from repro.fixedpoint import QFormat
         fmt = QFormat(32, 20)  # the README's Q11.20 reference format
 
-    if args.mode == "streams":
-        res = serve_streams(
-            _demo_streams(args.requests, args.history, args.live),
-            backend=args.backend, chunk_t=args.chunk_t, fmt=fmt,
-            decode_t=args.decode_t,
-            pipeline_depth=args.pipeline_depth,
-            block_c=args.block_c,
-            shards=args.shards,
-            # one device per shard: the pool raises when the host has
-            # fewer devices than shards instead of stacking them on one
-            shard_devices=(jax.devices()[:args.shards]
-                           if args.shards > 1 else None),
-            rebalance_every=args.rebalance_every,
-            # depth > 1 only pipelines in the async loop
-            measure_latency=args.pipeline_depth <= 1,
-            class_weights={"latency": 4.0, "bulk": 1.0},
-            arrivals_per_tick=args.arrivals_per_tick)
-        lat = res["chunk_latency"]
-        print(f"[serve] {res['requests']} requests, "
-              f"{res['samples']} samples in {res['wall_s']:.2f}s "
-              f"({res['requests_per_s']:.1f} req/s, "
-              f"{res['samples_per_s']:.0f} samples/s)")
-        print(f"[serve] chunk latency p50 {lat.get('p50_ms', 0):.2f}ms "
-              f"p95 {lat.get('p95_ms', 0):.2f}ms, "
-              f"queue wait p95 {res['queue_wait_ticks_p95']:.0f} ticks, "
-              f"{res['rejected_submits']} backpressured submits, "
-              f"{res['short_ticks']} decode-short ticks")
-        for cls, c in sorted(res["classes"].items()):
-            print(f"[serve]   class {cls}: {c['completed']} done, "
-                  f"queue wait p95 "
-                  f"{c.get('queue_wait_ticks_p95', 0):.0f} ticks")
-        if args.shards > 1:
-            print(f"[serve] {res['shards']} shards, "
-                  f"{res['migrations']} migrations, "
-                  f"final imbalance {res['imbalance']}")
-        print(f"[serve] flagged tenants: {res['flagged']}")
-        return
-
-    cfg = get_config(args.arch)
-    if args.scale == "tiny":
-        cfg = cfg.reduced()
-    res = serve(cfg, args.batch, args.prompt_len, args.gen,
-                backend=args.backend, chunk_t=args.chunk_t, fmt=fmt)
-    print(f"[serve] prefill {res['prefill_tok_s']:.1f} tok/s, "
-          f"decode {res['decode_tok_s']:.1f} tok/s")
-    print(f"[serve] TEDA-flagged requests: {res['flagged_requests']}")
-    print(f"[serve] monitor: {res['monitor']['ticks']} ticks, "
-          f"pool {res['monitor']['pool']}")
-    print(f"[serve] sample continuation (req 0): "
-          f"{res['tokens'][0][:16].tolist()}")
+    res = serve_streams(
+        _demo_streams(args.requests, args.history, args.live),
+        backend=args.backend, chunk_t=args.chunk_t, fmt=fmt,
+        decode_t=args.decode_t,
+        pipeline_depth=args.pipeline_depth,
+        block_c=args.block_c,
+        shards=args.shards,
+        # one device per shard: the pool raises when the host has
+        # fewer devices than shards instead of stacking them on one
+        shard_devices=(jax.devices()[:args.shards]
+                       if args.shards > 1 else None),
+        rebalance_every=args.rebalance_every,
+        # depth > 1 only pipelines in the async loop
+        measure_latency=args.pipeline_depth <= 1,
+        class_weights={"latency": 4.0, "bulk": 1.0},
+        arrivals_per_tick=args.arrivals_per_tick)
+    lat = res["chunk_latency"]
+    print(f"[serve] {res['requests']} requests, "
+          f"{res['samples']} samples in {res['wall_s']:.2f}s "
+          f"({res['requests_per_s']:.1f} req/s, "
+          f"{res['samples_per_s']:.0f} samples/s)")
+    print(f"[serve] chunk latency p50 {lat.get('p50_ms', 0):.2f}ms "
+          f"p95 {lat.get('p95_ms', 0):.2f}ms, "
+          f"queue wait p95 {res['queue_wait_ticks_p95']:.0f} ticks, "
+          f"{res['rejected_submits']} backpressured submits, "
+          f"{res['short_ticks']} decode-short ticks")
+    for cls, c in sorted(res["classes"].items()):
+        print(f"[serve]   class {cls}: {c['completed']} done, "
+              f"queue wait p95 "
+              f"{c.get('queue_wait_ticks_p95', 0):.0f} ticks")
+    if args.shards > 1:
+        print(f"[serve] {res['shards']} shards, "
+              f"{res['migrations']} migrations, "
+              f"final imbalance {res['imbalance']}")
+    print(f"[serve] flagged tenants: {res['flagged']}")
 
 
 if __name__ == "__main__":

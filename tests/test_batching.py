@@ -16,7 +16,7 @@ from dataclasses import asdict
 import numpy as np
 import pytest
 
-from conftest import given_or_cases
+from helpers import given_or_cases
 
 from repro.engine import PoolFull, SlotPool, StreamEngine, list_backends
 from repro.fixedpoint import QFormat

@@ -17,7 +17,7 @@ the reference semantics the fused ensemble kernel is held to
 import numpy as np
 import pytest
 
-from conftest import given_or_cases
+from helpers import given_or_cases
 
 from repro.detectors import (DEFAULT_DETECTORS, DETECTORS, aux_rows,
                              vote_threshold)

@@ -10,7 +10,7 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
-from conftest import given_or_cases
+from helpers import given_or_cases
 
 from repro.engine import (StreamEngine, engine_init, engine_step,
                           list_backends)

@@ -17,7 +17,7 @@ streams.  The `slow`-marked sweeps run the full-width K x C grid
 import numpy as np
 import pytest
 
-from conftest import given_or_cases
+from helpers import given_or_cases
 
 from repro.detectors import DEFAULT_DETECTORS, vote_threshold
 from repro.detectors.ensemble import ensemble_init, ensemble_ref, ensemble_scan

@@ -3,7 +3,7 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
-from conftest import given_or_cases
+from helpers import given_or_cases
 
 from repro.fixedpoint import QFormat, teda_q_scan_chan
 from repro.kernels.ops import teda_q_scan_tpu, teda_scan_tpu

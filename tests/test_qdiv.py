@@ -11,7 +11,7 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
-from conftest import given_or_cases
+from helpers import given_or_cases
 
 from repro.fixedpoint.qformat import QFormat, div_qi, div_qq
 from repro.kernels.qdiv import fast_div_qi, fast_div_qq

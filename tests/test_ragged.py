@@ -20,7 +20,7 @@ The hypothesis-driven cases run a trimmed width by default;
 import numpy as np
 import pytest
 
-from conftest import given_or_cases
+from helpers import given_or_cases
 
 from repro.engine import StreamEngine, list_backends
 from repro.fixedpoint import QFormat

@@ -14,7 +14,7 @@ all on (`REPRO_VIRTUAL_DEVICES=8`).
 import numpy as np
 import pytest
 
-from conftest import given_or_cases, virtual_devices
+from helpers import given_or_cases, virtual_devices
 
 from repro.engine import (HashRing, PoolFull, ShardedPool, SlotPool,
                           stable_hash)

@@ -19,6 +19,8 @@ from repro.engine import (EngineState, ShardedPool, SlotPool, StreamEngine,
 from repro.fixedpoint import QFormat
 from repro.obs import TickTracer, compile_watch
 
+from helpers import active_fetches
+
 FMT = QFormat(32, 20)
 T = 8
 
@@ -209,11 +211,6 @@ def test_pool_records_the_spans_it_declares():
         ("release", 1, "p", True), ("release", 1, "p", False)]
 
 
-def _fetches(registry) -> int:
-    fam = registry.get("engine_active_fetches_total")
-    return int(sum(ch.value for _, ch in fam.series()))
-
-
 def test_active_mask_is_not_fetched_at_one_bucket():
     """500 acquire / process / release rounds at one bucket never read
     the device's active mask, and slot admin compiles nothing."""
@@ -222,13 +219,13 @@ def test_active_mask_is_not_fetched_at_one_bucket():
     x = np.zeros((2, 8), np.float32)
     pool.process(x, valid_lens=np.ones((8,), np.int32))  # compiles
     mark = compile_watch().mark()
-    before = _fetches(pool.registry)
+    before = active_fetches(pool.registry)
     for i in range(500):
         slot = pool.acquire(1)
         pool.process(x, valid_lens=np.full((8,), i % 3, np.int32))
         pool.release(slot)
         assert pool.occupancy == 3
-    assert _fetches(pool.registry) == before == 0
+    assert active_fetches(pool.registry) == before == 0
     assert pool.free_slots.tolist() == [3, 4, 5, 6, 7]
     assert compile_watch().since(mark)["compiles"] == 0
 
@@ -243,23 +240,23 @@ def test_active_mask_fetched_once_per_resize():
         grown = pool.acquire(1)                 # 2 -> 4
         assert pool.capacity == 4
         pool.process(np.zeros((2, 4), np.float32))
-        assert _fetches(pool.registry) == pool.resizes == 2 * cycle + 1
+        assert active_fetches(pool.registry) == pool.resizes == 2 * cycle + 1
         pool.release(grown)                     # 4 -> 2
         assert pool.capacity == 2 and pool.occupancy == 2
         pool.process(x)
-        assert _fetches(pool.registry) == pool.resizes == 2 * cycle + 2
+        assert active_fetches(pool.registry) == pool.resizes == 2 * cycle + 2
 
 
 def test_active_mask_fetched_once_per_migration():
     pool = ShardedPool("scan", shards=2, buckets=(4,))
     for i in range(4):
         pool.acquire(f"s{i}", shard=0)
-    assert _fetches(pool.registry) == 0
+    assert active_fetches(pool.registry) == 0
     for n, rid in enumerate(["s0", "s1", "s2"], start=1):
         pool.migrate(rid, 1)
         pool.process_shard(1, np.zeros((2, 4), np.float32))
         assert pool.pools[1].occupancy == n
-        assert _fetches(pool.registry) == n
+        assert active_fetches(pool.registry) == n
 
 
 def test_outside_state_assignment_refreshes_mirror():
@@ -269,7 +266,7 @@ def test_outside_state_assignment_refreshes_mirror():
     eng.state = st._replace(active=jnp.asarray([True, True, False, True]))
     assert eng.active_slots.tolist() == [0, 1, 3]
     assert eng.attach(n=1).tolist() == [2]
-    assert _fetches(eng.registry) == 1
+    assert active_fetches(eng.registry) == 1
     with pytest.raises(ValueError):             # the mirror is read-only
         eng.active_mask[0] = False
 

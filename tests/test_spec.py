@@ -24,7 +24,7 @@ state).
 import numpy as np
 import pytest
 
-from conftest import given_or_cases
+from helpers import given_or_cases
 from repro.detectors import (DEFAULT_DETECTORS, MOMENT_MEMBERS, aux_rows,
                              ensemble_spec)
 from repro.detectors.ensemble import (ensemble_init, ensemble_ref,

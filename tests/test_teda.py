@@ -3,7 +3,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from conftest import given_or_cases
+from helpers import given_or_cases
 
 from repro.core import (teda_init, teda_step, teda_stream, teda_scan,
                         teda_threshold)
@@ -175,8 +175,8 @@ def test_threshold_helper():
 
 def test_detectability_bound_k_le_m_squared():
     """zeta <= (k+1)/(2k) (eq 3 absorbs the sample), so eq (6) with m
-    cannot trip at k <= m^2 — DESIGN.md §7. Verified with an extreme
-    spike at every early position."""
+    cannot trip at k <= m^2, the detectability bound. Verified with an
+    extreme spike at every early position."""
     for spike_at in range(1, 9):  # k = spike_at + 1 <= 9 = m^2
         x = np.ones((10, 1), np.float32) * 5.0
         x[spike_at] = 1e6
