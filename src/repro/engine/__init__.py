@@ -14,13 +14,13 @@ from repro.engine.state import (EngineState, engine_attach, engine_detach,
                                 engine_step, slot_mask)
 from repro.engine.backends import (Backend, get_backend, list_backends,
                                    register_backend)
-from repro.engine.engine import StreamEngine
+from repro.engine.engine import SlotMajor, StreamEngine
 from repro.engine.pool import PoolFull, SlotPool
 from repro.engine.sharded import HashRing, ShardedPool, stable_hash
 
 __all__ = [
     "Backend", "get_backend", "list_backends", "register_backend",
-    "EngineState", "StreamEngine", "SlotPool", "PoolFull",
+    "EngineState", "SlotMajor", "StreamEngine", "SlotPool", "PoolFull",
     "HashRing", "ShardedPool", "stable_hash",
     "engine_init", "engine_process", "engine_step", "engine_reset",
     "engine_attach", "engine_detach", "slot_mask",

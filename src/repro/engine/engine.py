@@ -14,6 +14,8 @@ program serves prefill-heavy and decode-phase slots together), and the
 `active` participation mask is the vlen=0 special case kept as sugar,
 so a scheduler can freeze slots that have no data this step without
 releasing them (the continuous-batching suspend, `launch/batching.py`).
+A scheduler hands its chunk over slot-major (`SlotMajor`, (C, T)) and
+reads the verdicts back the same way; the transposes run on the device.
 
 With a `mesh`, chunk processing fans out over the channel axis via
 `shard_map` (`make_channel_fanout` below) — channels are
@@ -36,7 +38,16 @@ from repro.engine.state import (EngineState, engine_init, engine_process,
                                 engine_reset, slot_mask)
 from repro.obs import MetricsRegistry, auto_name
 
-__all__ = ["StreamEngine"]
+__all__ = ["SlotMajor", "StreamEngine"]
+
+
+class SlotMajor(np.ndarray):
+    """A host chunk laid out slot-major: (capacity, T), row c holding
+    slot c's samples, so a scheduler writes each slot's samples as one
+    contiguous row.  Mark a plain array with `frame.view(SlotMajor)`;
+    it passes through `SlotPool.process` / `ShardedPool.process_shard`
+    as it is, and `StreamEngine.process` transposes it on the device and
+    returns the per-sample verdicts slot-major too."""
 
 
 def make_channel_fanout(fn, mesh: Mesh, axis_name: str = "data"):
@@ -62,6 +73,17 @@ def make_channel_fanout(fn, mesh: Mesh, axis_name: str = "data"):
     )
 
 
+def _slot_major(core):
+    """`core` on a (C, T) chunk: transposed to (T, C) on the way in,
+    its per-sample `ecc` and `outlier` back to (C, T) on the way out,
+    and anything after them (the ensemble's (K, T, C) scores) as it
+    is.  The transposes run on the device, beside the kernel."""
+    def rows(x, *args):
+        st, (ecc, outlier, *rest) = core(x.T, *args)
+        return st, (ecc.T, outlier.T, *rest)
+    return rows
+
+
 @jax.jit
 def _settle(state: EngineState, touched, active) -> EngineState:
     """Every attach, detach and reset since the state was last read, in
@@ -81,6 +103,7 @@ class StreamEngine:
 
     >>> eng = StreamEngine(capacity=256, backend="pallas", m=3.0)
     >>> verdicts = eng.process(chunk)          # chunk: (T, 256)
+    >>> rows = eng.process(frame.view(SlotMajor))  # frame: (256, T)
     >>> eng.reset([7])                         # recycle slot 7 mid-flight
     >>> eng.detach([3]); eng.attach([3], m=2.5)  # slot 3: new tenant
 
@@ -118,7 +141,7 @@ class StreamEngine:
             "valid_lens)", ("engine",)).labels(**lbl)
         self._c_programs = self.registry.counter(
             "engine_programs_compiled_total",
-            "distinct (capacity, T) program shapes executed",
+            "distinct (capacity, T, layout) programs executed",
             ("engine",)).labels(**lbl)
         # the host mirror of state.active (`active_mask`) is read back
         # from the device only after a state assigned from outside
@@ -158,10 +181,10 @@ class StreamEngine:
         # per-slot outlier sensitivity, eq (6) m — float even on the Q
         # path (the backend quantizes m^2+1 itself)
         self._m = np.full((self.capacity,), self.default_m, np.float32)
-        # chunk lengths this engine has executed: together with the
-        # capacity, T keys the jit program cache, so a flat set after
-        # warmup means no tick recompiles (the adaptive-chunk guarantee
-        # surfaced through SlotPool.stats()["programs"])
+        # (T, layout) of every chunk this engine has executed: together
+        # with the capacity they key the jit program cache, so a flat
+        # set after warmup means no tick recompiles (the adaptive-chunk
+        # guarantee surfaced through SlotPool.stats()["programs"])
         self._t_shapes: set = set()
 
         if self._ensemble:
@@ -189,7 +212,11 @@ class StreamEngine:
                     f"capacity {self.capacity} not divisible by mesh "
                     f"axis {axis_name!r} ({n_shards} shards)")
             core = make_channel_fanout(core, mesh, axis_name)
+        # one core, two entries: the (T, C) chunk and the slot-major
+        # (C, T) frame, the latter transposed around the (fanned-out)
+        # core so that sharding propagates through the transposes
         self._fn = jax.jit(core)
+        self._fn_rows = jax.jit(_slot_major(core))
 
     @property
     def state(self) -> EngineState:
@@ -380,7 +407,8 @@ class StreamEngine:
         self._m[idx] = m
 
     # ------------------------------------------------------ processing
-    def _account(self, t_len: int, vc, had_vlens: bool, active) -> None:
+    def _account(self, t_len: int, layout: str, vc, had_vlens: bool,
+                 active) -> None:
         """Update the obs instruments for one `process` call.
 
         `vc` is the concrete valid_lens (None when traced under an
@@ -388,8 +416,8 @@ class StreamEngine:
         skipped; calls/programs still count).
         """
         t_key = int(t_len)
-        if t_key not in self._t_shapes:
-            self._t_shapes.add(t_key)
+        if (t_key, layout) not in self._t_shapes:
+            self._t_shapes.add((t_key, layout))
             self._c_programs.inc()
         self._c_calls.inc()
         if had_vlens and vc is None:
@@ -408,7 +436,15 @@ class StreamEngine:
 
     def process(self, x: jnp.ndarray, active=None,
                 valid_lens=None) -> dict:
-        """Feed one (T, capacity) chunk; returns per-sample verdicts.
+        """Feed one (T, capacity) chunk, or one slot-major (capacity, T)
+        `SlotMajor` frame; returns per-sample verdicts.
+
+        A `SlotMajor` frame is transposed to (T, C) on the device, inside
+        the jitted call, and `ecc` and `outlier` come back slot-major,
+        (C, T): row c is slot c's verdicts, for a host that reads each
+        slot's as one contiguous row.  The ensemble's (K, T, C)
+        `scores` keep their layout.  Both layouts run the same program
+        around the kernel and give the same values, bit for bit.
 
         `valid_lens` makes the call ragged: a scalar or per-slot
         (capacity,) int vector, slot c retires exactly valid_lens[c]
@@ -428,11 +464,14 @@ class StreamEngine:
         compute and fetch verdicts only when it consumes them
         (`launch/batching.py`'s double-buffered loop).
         """
+        rows = isinstance(x, SlotMajor)
         x = jnp.asarray(x)
-        if x.ndim != 2 or x.shape[1] != self.capacity:
-            raise ValueError(
-                f"chunk must be (T, {self.capacity}), got {x.shape}")
-        t_len = x.shape[0]
+        if x.ndim != 2 or x.shape[int(not rows)] != self.capacity:
+            want = (f"({self.capacity}, T) slot-major" if rows
+                    else f"(T, {self.capacity})")
+            raise ValueError(f"chunk must be {want}, got {x.shape}")
+        t_len = x.shape[int(rows)]
+        fn = self._fn_rows if rows else self._fn
         st = self.state
         part = st.active if active is None else jnp.logical_and(
             st.active, slot_mask(active, self.capacity))
@@ -466,9 +505,10 @@ class StreamEngine:
         if self._mesh is None and not self._ensemble \
                 and (mv == mv[0]).all():
             mv = mv[0]
-        self._account(t_len, vc, valid_lens is not None, active)
+        self._account(t_len, "slot" if rows else "time", vc,
+                      valid_lens is not None, active)
         if self._ensemble:
-            (k, mean, var, aux), (bits, vote, scores) = self._fn(
+            (k, mean, var, aux), (bits, vote, scores) = fn(
                 x, st.k, st.mean, st.var, st.aux, vl,
                 jnp.asarray(self.backend.quantize_m(mv)),
                 jnp.asarray(self._det_w), jnp.asarray(self._det_thr))
@@ -480,7 +520,7 @@ class StreamEngine:
             # the (K, T, C) per-detector float score-stream block.
             return {"ecc": bits, "outlier": vote, "det_flags": bits,
                     "scores": scores}
-        (k, mean, var), (ecc, outlier) = self._fn(
+        (k, mean, var), (ecc, outlier) = fn(
             x, st.k, st.mean, st.var, vl,
             jnp.asarray(self.backend.quantize_m(mv)))
         self._state = EngineState(k=k, mean=mean, var=var,
@@ -504,8 +544,10 @@ class StreamEngine:
 
     @property
     def program_shapes(self) -> list:
-        """Sorted chunk lengths T this engine has executed — each is
-        one entry of the jit program cache at this capacity."""
+        """Sorted (T, layout) of the chunks this engine has executed,
+        layout "time" for a (T, C) chunk and "slot" for a `SlotMajor`
+        frame — each is one entry of the jit program cache at this
+        capacity."""
         return sorted(self._t_shapes)
 
     def teda_state(self) -> TedaState:

@@ -266,7 +266,10 @@ class SlotPool:
 
     # ------------------------------------------------------- processing
     def process(self, x, active=None, valid_lens=None) -> dict:
-        """Feed one (T, capacity) chunk to the current bucket's engine.
+        """Feed one (T, capacity) chunk, or one slot-major (capacity, T)
+        `SlotMajor` frame, to the current bucket's engine.  A slot-major
+        frame gets its `ecc` and `outlier` back slot-major, (capacity,
+        T), transposed on the device (see `StreamEngine.process`).
 
         `active` is the per-call participation mask and `valid_lens`
         the per-slot ragged retire counts (see `StreamEngine.process`);
@@ -286,11 +289,13 @@ class SlotPool:
                                    valid_lens=valid_lens)
 
     def programs(self) -> list:
-        """Every (capacity, T) program-cache key executed so far,
-        across all cached bucket engines.  Flat after warmup = the
-        adaptive-chunk path recompiles nothing."""
-        return sorted((cap, t) for cap, eng in self._engines.items()
-                      for t in eng.program_shapes)
+        """Every (capacity, T, layout) program-cache key executed so
+        far, across all cached bucket engines (layout "slot" for a
+        `SlotMajor` frame, "time" for a (T, C) chunk).  Flat after
+        warmup = the adaptive-chunk path recompiles nothing."""
+        return sorted((cap, t, layout)
+                      for cap, eng in self._engines.items()
+                      for t, layout in eng.program_shapes)
 
     def stats(self) -> dict:
         return {"bucket": self._bucket, "buckets": list(self.buckets),

@@ -302,7 +302,10 @@ class ShardedPool:
     # ----------------------------------------------------- processing
     def process_shard(self, shard: int, x, active=None,
                       valid_lens=None) -> dict:
-        """Feed one (T, shard_capacity(shard)) chunk to one shard.
+        """Feed one (T, shard_capacity(shard)) chunk, or one slot-major
+        (shard_capacity(shard), T) `SlotMajor` frame, to one shard; a
+        slot-major frame gets its `ecc` and `outlier` back slot-major
+        (see `SlotPool.process`).
 
         Per-shard calls are independent JAX async dispatches — a
         scheduler ticks every shard without a barrier between them

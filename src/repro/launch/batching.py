@@ -55,7 +55,7 @@ Three scheduler-level optimisations ride on that fused call:
     (decode_t, C) program instead of the full (chunk_t, C) one:
     decode-only ticks stop paying a chunk_t-deep program to retire one
     sample per slot.  Both shapes are cached per capacity bucket (the
-    jit program cache keyed on (capacity, t) — see
+    jit program cache keyed on (capacity, t, layout) — see
     `SlotPool.stats()["programs"]`), so after warmup no tick
     recompiles.
 
@@ -88,7 +88,7 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
-from repro.engine import PoolFull, ShardedPool, SlotPool
+from repro.engine import PoolFull, ShardedPool, SlotMajor, SlotPool
 from repro.obs import (EventBus, LATENCY_MS_BUCKETS, MetricsRegistry,
                        NULL_SPAN, NULL_TRACER, TICK_BUCKETS, auto_name)
 
@@ -779,12 +779,14 @@ class BatchingScheduler:
 
     def _dispatch_group(self, members: List[_Run],
                         shard: int) -> None:
-        """One fused ragged (t, C) engine call on one shard: slot c
-        retires min(pending_c, t) samples via the per-slot
-        valid-length vector; everyone else is suspended at vlen=0.
-        Decode-only ticks (every member's pending <= decode_t) ride
-        the short cached (decode_t, C) program instead of the full
-        chunk.  Traced as an `assemble` span (the chunk and
+        """One fused ragged engine call on one shard: slot c retires
+        min(pending_c, t) samples via the per-slot valid-length vector;
+        everyone else is suspended at vlen=0.  The chunk is a
+        slot-major (C, t) `SlotMajor` frame: each member's samples are
+        one contiguous row write, and the engine transposes the frame
+        on the device.  Decode-only ticks (every member's pending <=
+        decode_t) ride the short cached (decode_t, C) program instead
+        of the full chunk.  Traced as an `assemble` span (the frame and
         valid-length vector built on host) then a `dispatch` span (the
         pool and engine call)."""
         tracer = self.tracer
@@ -798,11 +800,11 @@ class BatchingScheduler:
                 t_len = self.decode_t
                 self._c_short.inc()
             span.set(t=t_len)
-            x = np.zeros((t_len, cap), np.float32)
+            x = np.zeros((cap, t_len), np.float32)
             vlens = np.zeros((cap,), np.int32)
             for run in members:
                 n = min(run.avail, t_len)
-                x[:n, run.slot] = run.take(n)
+                x[run.slot, :n] = run.take(n)
                 if not run.avail:
                     del self._ready[run.req.rid]
                 vlens[run.slot] = n
@@ -810,6 +812,7 @@ class BatchingScheduler:
             slots = np.fromiter((r.slot for r in members), np.intp,
                                 len(members))
             ns = vlens[slots].astype(np.intp)
+            x = x.view(SlotMajor)
         self._c_calls.inc()
         h2d = x.nbytes + vlens.nbytes
         self._c_h2d.inc(h2d)
@@ -840,6 +843,8 @@ class BatchingScheduler:
         flagged rids are deferred into the next tick's events.
         Every member's verdict streams on the event bus here — this is
         the retirement moment, the earliest a verdict exists on host.
+        The flags and the bitmask arrive slot-major, (C, t), as the
+        engine returns them for a `SlotMajor` frame.
         Traced as a `retire` span (the fetch, with the bytes it moves)
         then an `account` span (everything after it).
         """
@@ -876,8 +881,10 @@ class BatchingScheduler:
         histogram, every member's counter row by one array update per
         field, the flag counters, then per member only its latency
         pair, its `collect` parts and its `chunk_retired` event.  The
-        gathered outlier and bitmask rows are read-only, so `collect`
-        and the events keep views of them, not copies."""
+        slot-major outlier and bitmask arrive with one contiguous row
+        per slot, so the members' rows are one gather of whole rows;
+        the gathered rows are read-only, so `collect` and the events
+        keep views of them, not copies."""
         wall = (inf.sync_wall if inf.sync_wall is not None
                 else time.perf_counter() - inf.t0)
         runs, slots, ns = inf.runs, inf.slots, inf.ns
@@ -894,18 +901,17 @@ class BatchingScheduler:
         self._h_wall.observe(wall * 1e3, weight=max(retired, 1))
         self._c_samples.inc(retired)
         # every member's rows gathered once, (slots, t) up to the longest
-        # it retired: row i holds runs[i]'s column, valid over its
-        # first n samples (the engine zeroes each slot's outputs past
-        # its valid rows)
+        # it retired: row i is runs[i]'s slot, valid over its first n
+        # samples (the engine zeroes each slot's outputs past them)
         t_max = int(ns.max())
-        cols = np.ascontiguousarray(outlier[:t_max, slots].T)
+        cols = outlier[slots, :t_max]
         cols.flags.writeable = False
         n_flags = cols.sum(axis=1)
         counts = sums = bits = None
         if self._ensemble:
             counts, sums, bits = self._members(ecc, scores, slots, t_max)
         elif self.collect:
-            bits = np.ascontiguousarray(ecc[:t_max, slots].T)
+            bits = ecc[slots, :t_max]
             bits.flags.writeable = False
         acc = self._rows
         acc.samples[rows] += ns
@@ -989,21 +995,21 @@ class BatchingScheduler:
         read-only bitmask rows (each member's first n samples valid;
         None without `collect`).  Counts the per-detector flag
         counters.  The engine zeroes the bitmask and the scores past
-        each slot's valid rows, so columns are reduced whole, up to the
-        call's longest `t_max`: gathered first when the call holds few
-        of the pool's slots, else reduced first and then picked.
-        Traced as a `members` span (`k` detectors, `slots`)."""
+        each slot's valid rows, so rows are reduced whole, up to the
+        call's longest `t_max`.  The slot-major bitmask is gathered by
+        row first, and the gathered rows are the `collect` rows; the
+        (K, t, C) scores are gathered first when the call holds few of
+        the pool's slots, else reduced first and then picked.  Traced
+        as a `members` span (`k` detectors, `slots`)."""
         tracer = self.tracer
         names = self._det_names
         with (tracer.span("members", device=True, tick=self.tick_no,
                           k=len(names), slots=len(slots))
               if tracer.enabled else NULL_SPAN):
-            few = 4 * len(slots) < ecc.shape[1]
-            pick = slice(None) if few else slots
             # bit d of the "ecc" bitmask is detectors[d]
-            bits = ecc[:t_max, slots] if few else ecc[:t_max]
-            counts = np.stack([np.count_nonzero(bits & (1 << d), axis=0)
-                               for d in range(len(names))])[:, pick]
+            bits = ecc[slots, :t_max]
+            counts = np.stack([np.count_nonzero(bits & (1 << d), axis=1)
+                               for d in range(len(names))])
             for d, det in enumerate(names):
                 total = int(counts[d].sum())
                 if total:
@@ -1012,14 +1018,12 @@ class BatchingScheduler:
             if scores is not None:
                 # row d of the score block is detectors[d]'s float
                 # score stream
-                sc = scores[:, :t_max, slots] if few else scores[:, :t_max]
-                sums = sc.sum(axis=1)[:, pick].T
-            rows = None
-            if self.collect:
-                rows = np.ascontiguousarray(
-                    (bits if few else bits[:, slots]).T)
-                rows.flags.writeable = False
-            return counts.T, sums, rows
+                if 4 * len(slots) < scores.shape[2]:
+                    sums = scores[:, :t_max, slots].sum(axis=1).T
+                else:
+                    sums = scores[:, :t_max].sum(axis=1)[:, slots].T
+            bits.flags.writeable = False
+            return counts.T, sums, bits if self.collect else None
 
     def _fold(self, run: _Run) -> None:
         """Copy a running request's counter row into its RequestStats."""
@@ -1298,9 +1302,9 @@ class BatchingScheduler:
         per-priority-class state counts plus queue-wait and
         completion-latency percentiles over *every* request the class
         ever saw (retention eviction no longer shifts them);
-        `programs` lists the (capacity, t) program cache — its size
-        going flat after warmup is the no-recompile guarantee of the
-        adaptive path.
+        `programs` lists the (capacity, t, layout) program cache — its
+        size going flat after warmup is the no-recompile guarantee of
+        the adaptive path.
         """
         lat = {}
         if self._h_wall.count:

@@ -252,8 +252,9 @@ def test_adaptive_decode_short_program():
     assert [c["retired"] for c in log] == [8, 2, 1, 1, 1, 1, 1]
     assert sched.short_ticks == 5
     st = sched.stats()
-    # two cached programs at bucket 2, nothing else ever compiled
-    assert st["programs"] == [(2, 1), (2, 8)]
+    # two cached programs at bucket 2, both taking the slot-major
+    # frame, nothing else ever compiled
+    assert st["programs"] == [(2, 1, "slot"), (2, 8, "slot")]
     assert sched.telemetry("a").samples == 15
 
 
@@ -695,6 +696,10 @@ class _PerMemberLoop(BatchingScheduler):
         pass  # the per-member loop keeps RequestStats current itself
 
     def _account(self, inf, outlier, ecc, scores, events):
+        # the fetched flags and bitmask arrive slot-major, (C, t): back
+        # to (t, C) for the per-member loop below
+        outlier = outlier.T
+        ecc = None if ecc is None else ecc.T
         wall = (inf.sync_wall if inf.sync_wall is not None
                 else time.perf_counter() - inf.t0)
         members = list(zip(inf.runs, inf.slots.tolist(), inf.ns.tolist()))

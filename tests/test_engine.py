@@ -10,10 +10,10 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
-from helpers import given_or_cases
+from helpers import given_or_cases, virtual_devices
 
-from repro.engine import (StreamEngine, engine_init, engine_step,
-                          list_backends)
+from repro.engine import (ShardedPool, SlotMajor, StreamEngine,
+                          engine_init, engine_step, list_backends)
 from repro.fixedpoint import QFormat
 from repro.kernels.ref import teda_ref
 
@@ -303,3 +303,71 @@ def test_fanout_capacity_divisibility():
     import jax
     mesh = jax.make_mesh((1,), ("data",), (jax.sharding.AxisType.Auto,))
     StreamEngine(4, "scan", mesh=mesh)  # divisible: fine
+
+
+# ------------------------------------------------- slot-major frames
+SLOT_C = 8
+ENSEMBLE = ("teda", "rde", "zscore", "hst", "teda-q")
+
+
+def _slot_major_target(backend, where):
+    """A fresh engine (plain, on a mesh of 1 or 2 devices, or one
+    shard of a ShardedPool) as (process, state): process(x, vlens)
+    feeds the chunk as it is given, state() reads the packed state."""
+    import jax
+    opts = dict(fmt=FMT, block_t=32)
+    if backend == "ensemble":
+        opts["detectors"] = ENSEMBLE
+    if where == "shard":
+        pool = ShardedPool(backend, shards=2, buckets=(SLOT_C,), **opts)
+        for i in range(10):
+            pool.acquire(f"r{i}")
+        s, _ = pool.lookup("r0")
+        assert pool.shard_capacity(s) == SLOT_C
+        return (lambda x, v: pool.process_shard(s, x, valid_lens=v),
+                lambda: pool.pools[s].engine.state)
+    if where.startswith("mesh"):
+        devs = (jax.devices()[:1] if where == "mesh1"
+                else virtual_devices(2))
+        opts["mesh"] = jax.make_mesh(
+            (len(devs),), ("data",), (jax.sharding.AxisType.Auto,),
+            devices=devs)
+    eng = StreamEngine(SLOT_C, backend, **opts)
+    eng.detach([5])   # a detached slot beside the suspended ones
+    return (lambda x, v: eng.process(x, valid_lens=v),
+            lambda: eng.state)
+
+
+SLOT_MAJOR_CASES = (
+    [(b, t, "engine") for b in ("pallas-q", "scan", "pallas", "ensemble")
+     for t in (1, 37)]
+    + [(b, t, w) for w in ("mesh1", "mesh2") for b in ("pallas-q", "scan")
+       for t in (1, 37)]
+    + [(b, 37, "shard") for b in ("pallas-q", "ensemble")])
+
+
+@pytest.mark.parametrize("backend,t,where", SLOT_MAJOR_CASES)
+def test_slot_major_frame_equals_time_major(backend, t, where):
+    """A `SlotMajor` (C, T) frame gives the (T, C) chunk's outputs
+    transposed, and the same final state, bit for bit: ragged valid
+    lengths with suspended slots, carried over three calls."""
+    (proc_t, state_t) = _slot_major_target(backend, where)
+    (proc_s, state_s) = _slot_major_target(backend, where)
+    rng = np.random.default_rng(t)
+    for call in range(3):
+        x = _x(t, SLOT_C, seed=100 * t + call)
+        vl = rng.integers(0, t + 1, SLOT_C).astype(np.int32)
+        vl[0], vl[1] = t, 0   # one full slot, one suspended
+        want = proc_t(x, vl)
+        got = proc_s(np.ascontiguousarray(x.T).view(SlotMajor), vl)
+        assert set(got) == set(want)
+        for key, w in want.items():
+            g, w = np.asarray(got[key]), np.asarray(w)
+            if key != "scores":   # (K, T, C) either way
+                assert g.shape == (SLOT_C, t), key
+                g = g.T
+            assert g.dtype == w.dtype and g.shape == w.shape, key
+            assert g.tobytes() == w.tobytes(), (key, call)
+    for a, b in zip(state_s(), state_t()):
+        if b is not None:
+            assert np.asarray(a).tobytes() == np.asarray(b).tobytes()

@@ -38,7 +38,8 @@ def test_serve_streams_priority_classes_and_telemetry():
     assert prios["t1"] == "latency" and prios["t0"] == "bulk"
     # decode trickle ticks rode the short cached program
     assert res["short_ticks"] > 0
-    assert all(len(key) == 2 for key in res["programs"])
+    assert all(len(key) == 3 and key[2] == "slot"
+               for key in res["programs"])
 
 
 def test_serve_streams_async_matches_sync_flags():
